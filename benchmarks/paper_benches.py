@@ -289,10 +289,11 @@ import jax, jax.numpy as jnp, numpy as np
 sys.path.insert(0, "src")
 from repro.plan import build_plan
 from repro.plan.lower_shard_map import _lower_shard_map
+from repro.mesh import make_mesh
 
 q, n = 2, 512
 devs = np.array(jax.devices())
-mesh = jax.make_mesh((q, q), ("x", "y"), devices=devs[:q*q])
+mesh = make_mesh((q, q), ("x", "y"), devices=devs[:q*q])
 rng = np.random.default_rng(0)
 a = jnp.asarray(rng.standard_normal((n, n)), jnp.float32)
 b = jnp.asarray(rng.standard_normal((n, n)), jnp.float32)
@@ -325,8 +326,7 @@ def bench_overlap_vs_staged() -> List[Row]:
     double-buffer lowering.  Also asserts bitwise-identical outputs (the
     overlapped torus body is a pure dataflow reorder)."""
     margin = float(os.environ.get("OVERLAP_DRIFT_MARGIN", "0.10"))
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_env()
     res = subprocess.run(
         [sys.executable, "-c", _OVERLAP_PROBE, "4"],
         capture_output=True, text=True, env=env, cwd=_repo_root(),
@@ -371,10 +371,11 @@ import jax, jax.numpy as jnp, numpy as np
 sys.path.insert(0, "src")
 from repro.plan import build_plan
 from repro.plan.lower_shard_map import _lower_shard_map
+from repro.mesh import make_mesh
 
 n = 512
 devs = np.array(jax.devices())
-mesh = jax.make_mesh((2, 2, 2), ("tree", "x", "y"), devices=devs[:8])
+mesh = make_mesh((2, 2, 2), ("tree", "x", "y"), devices=devs[:8])
 rng = np.random.default_rng(0)
 a = jnp.asarray(rng.standard_normal((n, n)), jnp.float32)
 b = jnp.asarray(rng.standard_normal((n, n)), jnp.float32)
@@ -404,8 +405,7 @@ def bench_fattree_vs_flat() -> List[Row]:
     speed guard -- on host CPU the two are link-indistinguishable; the
     ranking between them is the calibrated profile's job (see
     tests/test_fattree_exec.py's flip pin)."""
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_env()
     res = subprocess.run(
         [sys.executable, "-c", _FATTREE_PROBE, "8"],
         capture_output=True, text=True, env=env, cwd=_repo_root(),
@@ -440,6 +440,7 @@ import functools
 sys.path.insert(0, "src")
 from repro.dist import cannon_matmul, summa_matmul, pod25d_matmul
 from repro.dist.pod25d import cannon25d_matmul
+from repro.mesh import make_mesh
 from repro.roofline.hlo_stats import analyze
 
 mode = sys.argv[1]
@@ -447,7 +448,7 @@ devs = np.array(jax.devices())
 out = {}
 if mode == "cannon_summa":
     q, n = 4, 1024
-    mesh = jax.make_mesh((q, q), ("x", "y"), devices=devs[:q*q])
+    mesh = make_mesh((q, q), ("x", "y"), devices=devs[:q*q])
     a = jax.ShapeDtypeStruct((n, n), jnp.bfloat16)
     b = jax.ShapeDtypeStruct((n, n), jnp.bfloat16)
     for name, fn in (("cannon", cannon_matmul), ("summa", summa_matmul)):
@@ -464,11 +465,11 @@ elif mode == "pod25d":
     a = jax.ShapeDtypeStruct((n, n), jnp.bfloat16)
     b = jax.ShapeDtypeStruct((n, n), jnp.bfloat16)
     q, c = 4, 2
-    mesh1 = jax.make_mesh((q, q), ("x", "y"), devices=devs[:q*q])
+    mesh1 = make_mesh((q, q), ("x", "y"), devices=devs[:q*q])
     f1 = jax.jit(functools.partial(cannon_matmul, mesh=mesh1, axis_x="x", axis_y="y"))
     t0 = time.perf_counter()
     s1 = analyze(f1.lower(a, b).compile().as_text())
-    mesh2 = jax.make_mesh((c, q, q), ("pod", "x", "y"), devices=devs[:c*q*q])
+    mesh2 = make_mesh((c, q, q), ("pod", "x", "y"), devices=devs[:c*q*q])
     f2 = jax.jit(functools.partial(cannon25d_matmul, mesh=mesh2,
                                    pod_axis="pod", axis_x="x", axis_y="y"))
     s2 = analyze(f2.lower(a, b).compile().as_text())
@@ -483,8 +484,7 @@ print("PROBE_JSON:" + json.dumps(out))
 
 
 def _run_dist_probe(mode: str) -> dict:
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
+    env = _cpu_env()
     res = subprocess.run(
         [sys.executable, "-c", _PROBE, mode],
         capture_output=True, text=True, env=env, cwd=_repo_root(), timeout=600,
@@ -495,6 +495,16 @@ def _run_dist_probe(mode: str) -> dict:
     raise RuntimeError(
         f"probe {mode} failed:\n{res.stdout[-2000:]}\n{res.stderr[-2000:]}"
     )
+
+
+def _cpu_env() -> dict:
+    """Environment for a forced-host device-farm child: the parent's, minus
+    its device-count flag (each child sets its own), pinned to the CPU so
+    the child never contends for a chip."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    return env
 
 
 def _repo_root() -> str:

@@ -50,6 +50,12 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
+# Every bench here times the host CPU backend or the Pallas interpreter, and
+# several run forced-host device farms in child processes.  Pin this process
+# and its children to the CPU: no figure here passes for a chip measurement,
+# and no child contends for a chip another process holds.
+os.environ["JAX_PLATFORMS"] = "cpu"
+
 
 def _force_host_devices(env_var: str, default: int) -> None:
     """Set the forced-host device flag; must run before jax is imported."""

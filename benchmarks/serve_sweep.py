@@ -36,6 +36,9 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
 
 
 def _force_host_devices() -> int:
+    # forced-host device farm: pinned to the CPU, so that a machine with a
+    # chip cannot hand this sweep one real device in place of the farm
+    os.environ["JAX_PLATFORMS"] = "cpu"
     devices = int(os.environ.get("SERVE_SWEEP_DEVICES", "8"))
     flags = os.environ.get("XLA_FLAGS", "")
     if "host_platform_device_count" not in flags:
@@ -60,13 +63,15 @@ DEFAULT_GRID = (
 def _mesh(shape):
     import jax
 
+    from repro.mesh import make_mesh
+
     if shape is None:
         return None
     n = shape[0] * shape[1]
     devs = jax.devices()
     if len(devs) < n:
         raise RuntimeError(f"need {n} devices, have {len(devs)}")
-    return jax.make_mesh(shape, ("x", "y"), devices=devs[:n])
+    return make_mesh(shape, ("x", "y"), devices=devs[:n])
 
 
 def _prompts(rng, n, lo=2, hi=10, vocab=200):

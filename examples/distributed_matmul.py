@@ -18,6 +18,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.cost import torus_schedule_cost
 from repro.core.schedule import cannon_schedule
+from repro.mesh import make_mesh
 from repro.dist import (cannon_matmul, pod25d_matmul, ring_ag_matmul,
                         ring_rs_matmul, summa_matmul)
 from repro.roofline.hlo_stats import analyze
@@ -26,7 +27,7 @@ from repro.roofline.hlo_stats import analyze
 def main():
     devs = np.array(jax.devices())
     q, n = 4, 512
-    mesh = jax.make_mesh((q, q), ("x", "y"), devices=devs[: q * q])
+    mesh = make_mesh((q, q), ("x", "y"), devices=devs[: q * q])
     a = jax.random.normal(jax.random.PRNGKey(0), (n, n), jnp.bfloat16)
     b = jax.random.normal(jax.random.PRNGKey(1), (n, n), jnp.bfloat16)
     ref = (a.astype(jnp.float32) @ b.astype(jnp.float32)).astype(jnp.bfloat16)
@@ -53,7 +54,7 @@ def main():
           f"(x2 bytes bf16 = {2*rep.words_per_node:.3e} B)")
 
     print("\n=== 2.5D: contraction split over a pod axis (c=2) ===")
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
+    mesh3 = make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
     f25 = jax.jit(functools.partial(pod25d_matmul, mesh=mesh3, pod_axis="pod"))
     out = f25(a, b)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32))))
@@ -61,7 +62,7 @@ def main():
     print(f"pod25d   err={err:.3f}  coll_bytes/dev={stats.coll_bytes:.3e}")
 
     print("\n=== ring collective matmuls (1-D torus solutions) ===")
-    mesh_r = jax.make_mesh((8,), ("t",), devices=devs[:8])
+    mesh_r = make_mesh((8,), ("t",), devices=devs[:8])
     s, d, fdim = 512, 256, 256
     x = jax.random.normal(jax.random.PRNGKey(2), (s, d), jnp.bfloat16)
     w = jax.random.normal(jax.random.PRNGKey(3), (d, fdim), jnp.bfloat16)

@@ -24,6 +24,7 @@ import jax                                                   # noqa: E402
 import numpy as np                                           # noqa: E402
 
 from repro.configs import get_smoke_config                   # noqa: E402
+from repro.mesh import make_mesh                             # noqa: E402
 from repro.models.registry import build_model                # noqa: E402
 from repro.runtime.serve import ServeConfig                  # noqa: E402
 from repro.serve import Server                               # noqa: E402
@@ -51,7 +52,7 @@ def main():
             raise SystemExit(f"need 4 devices for the 2x2 mesh, have "
                              f"{len(devs)}; run with --no-mesh or set "
                              f"XLA_FLAGS=--xla_force_host_platform_device_count=4")
-        mesh = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+        mesh = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
 
     sc = ServeConfig(max_new_tokens=args.max_new, max_seq=128)
     server = Server(model, params, sc, mesh=mesh, strategy=args.strategy,

@@ -109,14 +109,33 @@ def optimal_replication(n: int, p: int, M: float) -> int:
 
 
 # ---------------------------------------------------------------------------
-# TPU hardware constants (v5e targets used across roofline + cost model)
+# Device peaks, keyed by ``jax.Device.device_kind``
 # ---------------------------------------------------------------------------
 
-PEAK_FLOPS_BF16 = 197e12  # per chip
-HBM_BW = 819e9            # bytes/s per chip
-ICI_BW = 50e9             # bytes/s per link (one direction)
-VMEM_BYTES = 128 * 1024 * 1024  # ~128 MiB v5e vector memory
-MXU_DIM = 128             # systolic array tile edge
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    bf16_flops: float        # FLOP/s per chip
+    hbm_bytes_per_s: float   # per chip
+    ici_bytes_per_s: float   # per link, one direction
+
+
+# Published per-chip peaks.  "TPU v5 lite" (TPU v5e): Google Cloud
+# documentation, "TPU v5e" -- 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+# 1,600 Gbit/s of inter-chip interconnect per chip, here split over the
+# chip's four ICI links (50 GB/s each way per link).  A kind missing from
+# this table has no peaks: callers report no roofline figure for it.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(bf16_flops=197e12, hbm_bytes_per_s=819e9,
+                               ici_bytes_per_s=50e9),
+}
+
+
+# The chip the analytic cost model and the dry-run roofline price against.
+PLAN_TARGET = "TPU v5 lite"
+PEAK_FLOPS_BF16 = DEVICE_PEAKS[PLAN_TARGET].bf16_flops
+HBM_BW = DEVICE_PEAKS[PLAN_TARGET].hbm_bytes_per_s
+ICI_BW = DEVICE_PEAKS[PLAN_TARGET].ici_bytes_per_s
 
 
 def calibrated_total_s(flops: float, comm_bytes: float, msgs: float, *,

@@ -23,20 +23,16 @@ and the entry points here are thin facades over it.
 Local block multiplies route through the Pallas matmul kernel on TPU/GPU
 and jnp.matmul with fp32 accumulation elsewhere (repro.dist.local).
 """
-from repro import jax_compat as _jax_compat
-
-_jax_compat.install()
-
-from ._util import pad_to  # noqa: E402
-from .api import (Estimate, applicable_strategies, choose, estimate,  # noqa: E402
+from ._util import pad_to
+from .api import (Estimate, applicable_strategies, choose, estimate,
                   symmetric_matmul)
-from .cannon import (cannon_matmul, executed_shift_vectors,  # noqa: E402
-                     lowered_plan, torus_body, torus_schedule_matmul)
-from .fattree import fattree_matmul  # noqa: E402
-from .local import local_matmul  # noqa: E402
-from .pod25d import cannon25d_matmul, pod25d_matmul  # noqa: E402
-from .ring import ring_ag_matmul, ring_rs_matmul  # noqa: E402
-from .summa import summa_matmul  # noqa: E402
+from .cannon import (cannon_matmul, executed_shift_vectors, lowered_plan,
+                     torus_body, torus_schedule_matmul)
+from .fattree import fattree_matmul
+from .local import local_matmul
+from .pod25d import cannon25d_matmul, pod25d_matmul
+from .ring import ring_ag_matmul, ring_rs_matmul
+from .summa import summa_matmul
 
 __all__ = [
     "Estimate", "applicable_strategies", "choose", "estimate",

@@ -9,9 +9,12 @@ simultaneously.  The contraction axis k stays innermost (contiguous revisits
 of the output block are required for legal accumulation on TPU, and k is the
 "time" axis of the systolic MXU -- the paper's Delta).
 
-Hardware adaptation notes (DESIGN.md Sec. 2): block shapes are multiples of
-the 128-wide MXU/VREG tiling; the fp32 accumulator lives in a VMEM scratch so
-low-precision inputs (bf16) accumulate at full precision.
+Hardware adaptation notes: block shapes are multiples of the 128-wide
+MXU/VREG tiling; the fp32 accumulator lives in a VMEM scratch so
+low-precision inputs (bf16) accumulate at full precision.  The kernel asks
+Mosaic for ``VMEM_LIMIT_BYTES`` of scoped VMEM, and every block choice
+(``default_blocks`` here, ``repro.tune.candidate_space``) keeps its working
+set within ``VMEM_BUDGET_BYTES`` of it.
 """
 from __future__ import annotations
 
@@ -24,6 +27,14 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.zorder import zorder_schedule
+
+# Scoped VMEM the kernel may use.  Mosaic's default scoped limit on v5e is
+# 16 MiB; an fp32 (512, 512, 2048) block set needs about 19 MiB and is
+# refused there.  32 MiB is a quarter of a v5e core's 128 MiB VMEM.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+# What a block choice's working set (``vmem_working_set_bytes``) may claim:
+# the limit less a quarter kept for Mosaic's own scratch.
+VMEM_BUDGET_BYTES = VMEM_LIMIT_BYTES * 3 // 4
 
 
 def _matmul_kernel(oi_ref, oj_ref, a_ref, b_ref, o_ref, acc_ref, *, nk: int):
@@ -97,6 +108,8 @@ def zorder_matmul(
         functools.partial(_matmul_kernel, nk=gk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(oi, oj, a, b)
 
@@ -109,12 +122,12 @@ def vmem_working_set_bytes(
 
     ``dtype_bytes`` is the *input* element width; the output block is sized
     by ``out_dtype_bytes`` when it differs (the accumulator is always fp32).
-    Must fit the ~128 MiB v5e VMEM with double-buffering headroom (x2 on the
-    streamed inputs)."""
+    The pipeline double-buffers the streamed A, B and output blocks.  Block
+    choices keep this within ``VMEM_BUDGET_BYTES``."""
     a = block_m * block_k * dtype_bytes * 2  # double-buffered
     b = block_k * block_n * dtype_bytes * 2
     acc = block_m * block_n * 4
-    out = block_m * block_n * (out_dtype_bytes or dtype_bytes)
+    out = block_m * block_n * (out_dtype_bytes or dtype_bytes) * 2
     return a + b + acc + out
 
 
@@ -126,7 +139,7 @@ def default_blocks(m: int, n: int, k: int, dtype_bytes: int = 2,
     bn = min(256, max(128, n))
     bk = min(2048, max(128, k))
     while vmem_working_set_bytes(bm, bn, bk, dtype_bytes,
-                                 out_dtype_bytes) > 96 * 1024 * 1024:
+                                 out_dtype_bytes) > VMEM_BUDGET_BYTES:
         if bk > 256:
             bk //= 2
         elif bm >= bn and bm > 128:

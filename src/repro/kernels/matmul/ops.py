@@ -7,7 +7,8 @@ tile (the kernel is a throughput kernel; tiny matmuls belong to XLA).
 With ``repro.obs`` tracing enabled, eager (non-traced) calls are wrapped in
 a ``kernel.matmul`` span: wall time (block_until_ready'd) lands in the
 ``kernel.matmul.us`` histogram and achieved FLOPs are recorded against the
-roofline peak (``kernel.matmul.roofline_fraction``).  Disabled mode and
+output device's published bf16 peak (``kernel.matmul.roofline_fraction``;
+only for a ``device_kind`` in ``repro.core.cost.DEVICE_PEAKS``).  Disabled mode and
 calls under tracing (tracer operands inside shard_map/jit bodies) go
 straight to the jit'd kernel with zero added work.
 """
@@ -20,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.core.cost import PEAK_FLOPS_BF16
+from repro.core.cost import DEVICE_PEAKS
 
 from .kernel import default_blocks, zorder_matmul
 from .ref import matmul_ref
@@ -66,8 +67,10 @@ def matmul(
     flops = 2.0 * m * n * k
     obs.histogram("kernel.matmul.us").observe(dt * 1e6)
     obs.counter("kernel.matmul.flops").inc(flops)
-    obs.histogram("kernel.matmul.roofline_fraction").observe(
-        flops / dt / PEAK_FLOPS_BF16)
+    peaks = DEVICE_PEAKS.get(next(iter(out.devices())).device_kind)
+    if peaks is not None:
+        obs.histogram("kernel.matmul.roofline_fraction").observe(
+            flops / dt / peaks.bf16_flops)
     if min(m, n, k) >= _MIN_TILE:
         # ragged shapes are padded to block multiples silently inside the
         # jit; surface the overhead as padded FLOPs / useful FLOPs

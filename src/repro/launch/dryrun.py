@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede every other import: jax locks the device count on first init.
+# The 512 devices are a forced-host farm, so the dry run is pinned to the CPU.
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape)
 cell on the production meshes and record memory/cost/roofline analysis.
@@ -34,7 +36,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs import SHAPES, get_config, runnable_cells, skipped_cells
-from repro.launch.mesh import make_production_mesh
+from repro.mesh import make_production_mesh
 from repro.launch.specs import (abstract_cache, abstract_opt_state,
                                 abstract_params, input_specs)
 from repro.models.sharding_rules import (cache_shardings, param_shardings,

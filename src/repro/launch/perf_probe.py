@@ -63,11 +63,19 @@ def calibrate_main(args) -> None:
     """Default mode: probe the links, write the machine-profile JSON."""
     if args.devices > 1 and "host_platform_device_count" not in \
             os.environ.get("XLA_FLAGS", ""):
+        # a forced-host farm is a CPU run: pin it there, or a machine with
+        # a chip would calibrate one real device in place of the farm
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             f"{os.environ.get('XLA_FLAGS', '')} "
             f"--xla_force_host_platform_device_count={args.devices}").strip()
     import jax
     import numpy as np
+
+    from repro.compile_cache import enable_compile_cache
+    from repro.mesh import make_mesh
+
+    enable_compile_cache()
 
     mesh = None
     devs = np.array(jax.devices())
@@ -76,8 +84,7 @@ def calibrate_main(args) -> None:
         names = ("x", "y", "z")[:len(shape)] if len(shape) > 1 else ("t",)
         import math
 
-        mesh = jax.make_mesh(shape, names,
-                             devices=devs[:math.prod(shape)])
+        mesh = make_mesh(shape, names, devices=devs[:math.prod(shape)])
     tree_axes = tuple(a for a in args.tree_axes.split(",") if a)
     profile = probe_links(mesh, reps=args.reps, tree_axes=tree_axes)
     if args.tune:
@@ -104,12 +111,14 @@ def calibrate_main(args) -> None:
 
 def cell_probe_main(args) -> None:
     """Legacy perf-iteration mode (``--arch``): one cell, roofline terms."""
-    # must precede jax init: the cell probe needs a forced device farm
+    # must precede jax init: the cell probe needs a forced device farm,
+    # which is a CPU run (importing repro.launch.dryrun below pins it too)
+    os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
     from repro.configs import canonical
     from repro.launch.dryrun import lower_cell
-    from repro.launch.mesh import make_production_mesh
+    from repro.mesh import make_production_mesh
 
     overrides = dict(parse_override(kv) for kv in args.set)
 

@@ -14,10 +14,11 @@ from __future__ import annotations
 import argparse
 
 import jax
-import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config, get_smoke_config
 from repro.data.pipeline import DataConfig, batch_iterator
+from repro.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.runtime.train import Trainer, TrainConfig
 
@@ -29,8 +30,7 @@ def build_mesh(tp: int):
         return None
     tp = min(tp, n)
     dp = n // tp
-    return jax.make_mesh((dp, tp), ("data", "model"),
-                         devices=np.array(devs[: dp * tp]))
+    return make_mesh((dp, tp), ("data", "model"), devices=devs[: dp * tp])
 
 
 def main() -> None:
@@ -46,6 +46,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     model = build_model(cfg)
     mesh = build_mesh(args.tp)

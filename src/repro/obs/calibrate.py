@@ -49,8 +49,6 @@ def _probe_axis(mesh, axis: str, size_bytes: int, reps: int) -> float:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.jax_compat import shard_map
-
     ax_size = int(mesh.shape[axis])
     shard_words = max(size_bytes // 4, 1)
     perm = [(i, (i + 1) % ax_size) for i in range(ax_size)]
@@ -58,8 +56,8 @@ def _probe_axis(mesh, axis: str, size_bytes: int, reps: int) -> float:
     def body(x):
         return jax.lax.ppermute(x, axis, perm)
 
-    f = jax.jit(shard_map(body, mesh=mesh, in_specs=P(axis),
-                          out_specs=P(axis)))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(axis),
+                              out_specs=P(axis)))
     x = jnp.zeros((ax_size * shard_words,), jnp.float32)
     return _time_best(lambda: f(x), reps)
 
@@ -152,7 +150,7 @@ def probe_links(mesh=None, *,
             fit = fit_alpha_beta(sizes_bytes, times)
             links = [("ici", fit), ("local", fit)]
         return MachineProfile(
-            platform=jax.default_backend(),
+            device_kind=jax.devices()[0].device_kind,
             peak_flops=_probe_peak_flops(reps),
             links=tuple(links),
             created=datetime.datetime.now(

@@ -44,7 +44,7 @@ class MachineProfile:
     table covers the local bucket, alongside the fitted α–β comm terms --
     the repo's two calibration loops in one ranking."""
 
-    platform: str
+    device_kind: str
     peak_flops: float
     links: Tuple[Tuple[str, LinkParams], ...]
     created: str = ""
@@ -97,7 +97,7 @@ class MachineProfile:
     def to_json(self) -> Dict:
         obj = {
             "schema": self.schema,
-            "platform": self.platform,
+            "device_kind": self.device_kind,
             "peak_flops": self.peak_flops,
             "created": self.created,
             "links": {n: {"alpha_s": p.alpha_s,
@@ -122,7 +122,7 @@ class MachineProfile:
 
             tuning = TuningTable.from_json(obj["tuning"])
         return cls(
-            platform=obj.get("platform", "unknown"),
+            device_kind=obj.get("device_kind", "unknown"),
             peak_flops=float(obj["peak_flops"]),
             links=tuple(sorted(
                 (n, LinkParams(float(p["alpha_s"]),
@@ -152,7 +152,7 @@ def default_profile() -> MachineProfile:
     from repro.core import cost as _cost
 
     return MachineProfile(
-        platform="analytic",
+        device_kind="analytic",
         peak_flops=_cost.PEAK_FLOPS_BF16,
         links=(("ici", LinkParams(0.0, _cost.ICI_BW)),),
     )

@@ -31,7 +31,6 @@ from repro.dist.pod25d import (cannon25d_body, pod25d_slab_body,
                                pod25d_summa_overlapped_body)
 from repro.dist.ring import ring_ag_matmul, ring_rs_matmul
 from repro.dist.summa import summa_body, summa_overlapped_body
-from repro.jax_compat import shard_map
 
 from .ir import SchedulePlan
 from .lower_pallas import lower_pallas
@@ -101,7 +100,7 @@ def _lower_shard_map(plan: SchedulePlan):
         body_fn = (torus_program_body_overlapped if plan.overlap
                    else torus_program_body)
         body = body_fn(plan.torus, ax, ay, local_fn=local_fn)
-        f = shard_map(
+        f = _shard_map(
             lambda ab, bb: body(ab, bb).astype(out_dtype),
             mesh=mesh,
             in_specs=(P(ax, ay), P(ax, ay)),
@@ -112,7 +111,7 @@ def _lower_shard_map(plan: SchedulePlan):
     if plan.strategy == "summa":
         ax, ay = plan.axes
         summa_fn = summa_overlapped_body if plan.overlap else summa_body
-        f = shard_map(
+        f = _shard_map(
             summa_fn(ax, ay, out_dtype, local_fn=local_fn),
             mesh=mesh,
             in_specs=(P(ax, ay), P(ax, ay)),
@@ -122,7 +121,7 @@ def _lower_shard_map(plan: SchedulePlan):
 
     if plan.strategy == "fattree":
         tr, ax, ay = plan.axes
-        f = shard_map(
+        f = _shard_map(
             fattree_body(tr, ax, ay, plan.grid[0], out_dtype,
                          local_fn=local_fn),
             mesh=mesh,
@@ -133,7 +132,7 @@ def _lower_shard_map(plan: SchedulePlan):
 
     if plan.strategy == "cannon25d":
         pod, ax, ay = plan.axes
-        f = shard_map(
+        f = _shard_map(
             cannon25d_body(pod, ax, ay, plan.torus, out_dtype,
                            local_fn=local_fn, overlap=plan.overlap),
             mesh=mesh,
@@ -148,14 +147,14 @@ def _lower_shard_map(plan: SchedulePlan):
             ax, ay = plan.axes[1], plan.axes[2]
             pod_fn = (pod25d_summa_overlapped_body if plan.overlap
                       else pod25d_summa_body)
-            f = shard_map(
+            f = _shard_map(
                 pod_fn(pod, ax, ay, out_dtype, local_fn=local_fn),
                 mesh=mesh,
                 in_specs=(P(ax, (pod, ay)), P((pod, ax), ay)),
                 out_specs=P(ax, ay),
             )
         else:
-            f = shard_map(
+            f = _shard_map(
                 pod25d_slab_body(pod, out_dtype, local_fn=local_fn),
                 mesh=mesh,
                 in_specs=(P(None, pod), P(pod, None)),
@@ -167,7 +166,7 @@ def _lower_shard_map(plan: SchedulePlan):
         axis = plan.axes[0] if len(plan.axes) == 1 else tuple(plan.axes)
         if plan.strategy == "ring_ag":
             # sharded dims: m (rows of a) and n (cols of b)
-            f = shard_map(
+            f = _shard_map(
                 lambda xl, wl: ring_ag_matmul(xl, wl, axis,
                                               out_dtype=out_dtype,
                                               local_fn=local_fn),
@@ -177,7 +176,7 @@ def _lower_shard_map(plan: SchedulePlan):
             )
         else:
             # sharded dims: the contraction k and the output rows m
-            f = shard_map(
+            f = _shard_map(
                 lambda yl, wl: ring_rs_matmul(yl, wl, axis,
                                               out_dtype=out_dtype,
                                               local_fn=local_fn),
@@ -188,6 +187,15 @@ def _lower_shard_map(plan: SchedulePlan):
         return _padded(f, plan)
 
     raise ValueError(f"no shard_map lowering rule for {plan.strategy!r}")
+
+
+def _shard_map(body, *, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-axes (vma) type check.  On the
+    TPU a body's local multiply may be the Pallas kernel
+    (``repro.dist.local``), whose output type carries no vma, and the
+    checker refuses such an output inside a checked shard_map."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _padded(f, plan: SchedulePlan):

@@ -5,13 +5,9 @@
   hlo_stats -- call-graph walk over optimized HLO text: FLOPs, HBM bytes,
                collective bytes with while-loop trip multipliers
 """
-from repro import jax_compat as _jax_compat
-
-_jax_compat.install()
-
-from . import analysis, hlo_stats  # noqa: E402
-from .analysis import Roofline  # noqa: E402
-from .hlo_stats import Cost, analyze, analyze_by_shape  # noqa: E402
+from . import analysis, hlo_stats
+from .analysis import Roofline
+from .hlo_stats import Cost, analyze, analyze_by_shape
 
 __all__ = ["analysis", "hlo_stats", "Roofline", "Cost", "analyze",
            "analyze_by_shape"]

@@ -9,21 +9,13 @@ global batch preserved (per-device batch grows) or reduced, per config.
 """
 from __future__ import annotations
 
-from typing import Any, Optional, Sequence
+from typing import Any
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.sharding_rules import param_shardings
-
-
-def make_mesh(shape: Sequence[int], axes: Sequence[str],
-              devices: Optional[np.ndarray] = None) -> Mesh:
-    if devices is None:
-        n = int(np.prod(shape))
-        devices = np.array(jax.devices()[:n])
-    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices)
 
 
 def shrink_after_failure(mesh: Mesh, lost_pod: int = 0) -> Mesh:

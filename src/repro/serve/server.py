@@ -55,6 +55,8 @@ class ServeResult:
     step_latencies_s: np.ndarray      # per-token decode latency (after 1st)
     wall_s: float
     plan_probe: Dict[str, int]        # warm-plan cache probe accounting
+    prefill_logits: Optional[jax.Array] = None  # (requests, vocab), last
+                                                # prompt token's logits
 
     @property
     def generated_tokens(self) -> int:
@@ -226,6 +228,7 @@ class Server:
         with planned_scope(self.mesh, self.strategy, self.tuning):
             with obs.span("serve.prefill", batch=b_rows, seq=sp):
                 logits, cache = self._call_prefill(cache, tokens, offsets)
+            prefill_logits = logits
             if self.cfg.max_new_tokens > 0:
                 cur = _sample(logits, self.cfg, key)
                 jax.block_until_ready(cur)
@@ -263,7 +266,8 @@ class Server:
                 h.observe(dt * 1e6)
         return ServeResult(sequences, new_tokens,
                            bucket.label if bucket else None,
-                           ttft, np.asarray(step_lat), wall, probe)
+                           ttft, np.asarray(step_lat), wall, probe,
+                           prefill_logits[:n])
 
     # -- plan-cache accounting -----------------------------------------------
 

@@ -2,8 +2,8 @@
 
 ``candidate_space`` enumerates the (block_m, block_n, block_k, order)
 candidates for a shape -- every block a multiple of the 128-wide MXU tile,
-every working set within the same 96 MiB VMEM budget ``default_blocks``
-targets, orders the paper's Z-order schedule vs the row-major baseline.
+every working set within the kernel's ``VMEM_BUDGET_BYTES``, the budget
+``default_blocks`` also keeps to, orders the paper's Z-order schedule vs the row-major baseline.
 ``tune_shape`` times each candidate at the shape's bucket (best of
 ``reps`` timed calls, ``jax.block_until_ready``, discarded compile+warmup
 calls first) under ``tune.search`` obs spans and returns the winner as a
@@ -22,7 +22,8 @@ import time
 from typing import Dict, Iterable, Optional, Tuple
 
 from repro import obs
-from repro.kernels.matmul.kernel import vmem_working_set_bytes
+from repro.kernels.matmul.kernel import (VMEM_BUDGET_BYTES,
+                                         vmem_working_set_bytes)
 
 from .table import (MXU, Key, TunedBlocks, TuningTable, pad_up,
                     scaled_call_seconds, shape_bucket, table_key)
@@ -32,9 +33,6 @@ Candidate = Tuple[int, int, int, str]
 BLOCK_CANDIDATES = (128, 256, 512)
 BLOCK_K_CANDIDATES = (128, 256, 512, 1024, 2048)
 ORDERS = ("zorder", "rowmajor")
-# same budget default_blocks fits against: candidates never claim more VMEM
-# than the heuristic would allow itself
-VMEM_BUDGET_BYTES = 96 * 1024 * 1024
 
 
 def candidate_space(m: int, n: int, k: int, dtype_bytes: int = 2, *,
@@ -161,7 +159,7 @@ class Tuner:
         if self._device_kind is None:
             import jax
 
-            self._device_kind = jax.default_backend()
+            self._device_kind = jax.devices()[0].device_kind
         return self._device_kind
 
     def keys(self) -> Tuple[Key, ...]:

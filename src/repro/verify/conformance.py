@@ -452,6 +452,7 @@ def run_matrix(*, measure: bool = True, cases: Optional[Sequence[str]] = None,
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.mesh import make_mesh
     from repro.plan import build_plan
 
     devs = np.array(jax.devices())
@@ -473,7 +474,7 @@ def run_matrix(*, measure: bool = True, cases: Optional[Sequence[str]] = None,
                     try:
                         key = (shape, names)
                         if key not in meshes:
-                            meshes[key] = jax.make_mesh(
+                            meshes[key] = make_mesh(
                                 shape, names,
                                 devices=devs[:math.prod(shape)])
                         plan = build_plan(

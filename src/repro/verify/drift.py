@@ -159,6 +159,7 @@ def check_drift(*, profile_path: Optional[str] = None,
     import numpy as np
 
     from repro import obs
+    from repro.mesh import make_mesh
 
     devs = np.array(jax.devices())
     num_devices = len(devs) if num_devices is None else num_devices
@@ -169,8 +170,8 @@ def check_drift(*, profile_path: Optional[str] = None,
             continue
         key = (shape, names)
         if key not in meshes:
-            meshes[key] = jax.make_mesh(shape, names,
-                                        devices=devs[:math.prod(shape)])
+            meshes[key] = make_mesh(shape, names,
+                                    devices=devs[:math.prod(shape)])
         try:
             cells.append(measure_cell(strategy, meshes[key]))
         except Exception as e:  # noqa: BLE001 -- report every broken cell
@@ -185,7 +186,7 @@ def check_drift(*, profile_path: Optional[str] = None,
     if num_devices >= 4:
         mesh22 = meshes.get(((2, 2), ("x", "y")))
         if mesh22 is None:
-            mesh22 = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+            mesh22 = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
         fresh = obs.probe_links(mesh22)
         fresh_json = fresh.to_json()
         if stored is not None:

@@ -26,6 +26,7 @@ import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 
 from repro.plan import build_plan
+from repro.mesh import make_mesh
 from repro.verify import (ConformanceError, check, compare_records,
                           matrix_cells, measure_plan, run_matrix, trace_plan)
 
@@ -41,7 +42,7 @@ for strat in ("cannon", "summa", "pod25d", "cannon25d", "ring_ag", "ring_rs"):
 
 # --- one ragged + one batched + one bf16 measured cell ----------------------
 devs = np.array(jax.devices())
-mesh22 = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+mesh22 = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
 for kwargs in ({"m": 13, "n": 7, "k": 11},
                {"m": 5, "n": 8, "k": 12, "batch": (3,)},
                {"m": 16, "n": 16, "k": 16, "a_dtype": jnp.bfloat16,

@@ -13,12 +13,13 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import functools
 import jax, jax.numpy as jnp, numpy as np
+from repro.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.dist import (cannon_matmul, summa_matmul, pod25d_matmul,
                         ring_ag_matmul, ring_rs_matmul)
 
 devs = np.array(jax.devices())
-mesh22 = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+mesh22 = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
 M, K, N = 32, 24, 16
 a = jax.random.normal(jax.random.PRNGKey(0), (M, K), jnp.float32)
 b = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32)
@@ -31,11 +32,11 @@ assert float(jnp.max(jnp.abs(c - ref))) < tol, "cannon"
 c = jax.jit(functools.partial(summa_matmul, mesh=mesh22, axis_x="x", axis_y="y"))(a, b)
 assert float(jnp.max(jnp.abs(c - ref))) < tol, "summa"
 
-mesh_pod = jax.make_mesh((2,), ("pod",), devices=devs[:2])
+mesh_pod = make_mesh((2,), ("pod",), devices=devs[:2])
 c = jax.jit(functools.partial(pod25d_matmul, mesh=mesh_pod, pod_axis="pod"))(a, b)
 assert float(jnp.max(jnp.abs(c - ref))) < tol, "pod25d"
 
-mesh_r = jax.make_mesh((4,), ("t",), devices=devs[:4])
+mesh_r = make_mesh((4,), ("t",), devices=devs[:4])
 S, D, F = 16, 8, 12
 x = jax.random.normal(jax.random.PRNGKey(2), (S, D), jnp.float32)
 w = jax.random.normal(jax.random.PRNGKey(3), (D, F), jnp.float32)
@@ -57,7 +58,7 @@ agb = jax.jit(jax.shard_map(lambda xl, wl: ring_ag_matmul(xl, wl, "t"),
 assert float(jnp.max(jnp.abs(agb - xb @ w))) < tol, "ring_ag_batched"
 
 # 3-axis production-style mesh: 2.5D over pod composed with in-layer summa
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
+mesh3 = make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
 c = jax.jit(functools.partial(pod25d_matmul, mesh=mesh3, pod_axis="pod"))(a, b)
 assert float(jnp.max(jnp.abs(c - ref))) < tol, "pod25d_3axis"
 

@@ -182,7 +182,7 @@ def test_slow_tree_profile_flips_ranking_to_fattree():
     fast = LinkParams(alpha_s=0.0, bw_bytes_per_s=1e12)
     slow = LinkParams(alpha_s=1.0, bw_bytes_per_s=1e9)
     skewed = MachineProfile(
-        platform="cpu", peak_flops=1e18,
+        device_kind="cpu", peak_flops=1e18,
         links=(("ici", slow), ("dcn", slow), ("axis:tree", slow),
                ("axis:x", fast), ("axis:y", fast)))
     m, n, k = 64, 32, 512
@@ -205,6 +205,7 @@ import jax, jax.numpy as jnp, numpy as np
 
 from repro.dist import fattree_matmul
 from repro.plan import build_plan, execute_plan
+from repro.mesh import make_mesh
 from repro.verify import (ConformanceError, check, compare_records,
                           measure_plan, trace_plan)
 
@@ -212,7 +213,7 @@ devs = np.array(jax.devices())
 rng = np.random.default_rng(0)
 
 # numeric + measured-conformance cells: square, ragged, batched, bf16
-mesh8 = jax.make_mesh((2, 2, 2), ("tree", "x", "y"), devices=devs[:8])
+mesh8 = make_mesh((2, 2, 2), ("tree", "x", "y"), devices=devs[:8])
 for kwargs in ({"m": 24, "n": 24, "k": 24},
                {"m": 13, "n": 7, "k": 11},
                {"m": 5, "n": 8, "k": 12, "batch": (3,)},
@@ -231,7 +232,7 @@ for kwargs in ({"m": 24, "n": 24, "k": 24},
     check(plan, measure=True)
 
 # multi-level tree: 4 pods x (2 x 2), measured
-mesh16 = jax.make_mesh((4, 2, 2), ("tree", "x", "y"), devices=devs[:16])
+mesh16 = make_mesh((4, 2, 2), ("tree", "x", "y"), devices=devs[:16])
 plan16 = build_plan(24, 24, 24, mesh=mesh16, strategy="fattree",
                     use_cache=False)
 check(plan16, measure=True)

@@ -6,7 +6,8 @@ from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import attention_ref, mha
 from repro.kernels.matmul import matmul, matmul_ref, zorder_matmul
-from repro.kernels.matmul.kernel import default_blocks, vmem_working_set_bytes
+from repro.kernels.matmul.kernel import (VMEM_BUDGET_BYTES, default_blocks,
+                                         vmem_working_set_bytes)
 
 
 def _tol(dtype):
@@ -40,7 +41,7 @@ class TestZOrderMatmul:
         for dims in [(4096, 4096, 4096), (128, 32768, 256), (8192, 512, 8192)]:
             bm, bn, bk = default_blocks(*dims)
             assert bm % 128 == 0 and bn % 128 == 0 and bk % 128 == 0
-            assert vmem_working_set_bytes(bm, bn, bk) < 128 * 1024 * 1024
+            assert vmem_working_set_bytes(bm, bn, bk) <= VMEM_BUDGET_BYTES
 
     @settings(max_examples=60, deadline=None)
     @given(dims=st.tuples(st.sampled_from([128, 512, 4096, 32768]),
@@ -56,7 +57,7 @@ class TestZOrderMatmul:
         bm, bn, bk = default_blocks(*dims, dtype_bytes, out_dtype_bytes)
         assert bm % 128 == 0 and bn % 128 == 0 and bk % 128 == 0
         assert vmem_working_set_bytes(
-            bm, bn, bk, dtype_bytes, out_dtype_bytes) < 128 * 1024 * 1024
+            bm, bn, bk, dtype_bytes, out_dtype_bytes) <= VMEM_BUDGET_BYTES
 
     def test_tiny_fallback(self):
         a = jax.random.normal(jax.random.PRNGKey(4), (8, 16), jnp.float32)

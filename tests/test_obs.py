@@ -157,7 +157,7 @@ def test_metrics_snapshot_envelope():
 def test_profile_json_roundtrip(tmp_path):
     # links sorted by class name -- the canonical (from_json) order
     prof = MachineProfile(
-        platform="cpu", peak_flops=1e12,
+        device_kind="cpu", peak_flops=1e12,
         links=(("axis:x", LinkParams(2e-6, 5e9)),
                ("ici", LinkParams(1e-6, 1e10))),
         created="2026-08-08T00:00:00Z")
@@ -207,7 +207,7 @@ def test_latency_profile_flips_cannon_to_summa():
     analytic_top = rank_mesh_strategies(m, n, k, mesh)[0].strategy
     assert analytic_top == "cannon"
     latency = MachineProfile(
-        platform="synth", peak_flops=1e18,
+        device_kind="synth", peak_flops=1e18,
         links=(("ici", LinkParams(1.0, 1e18)),))
     ranked = rank_mesh_strategies(m, n, k, mesh, profile=latency)
     assert ranked[0].strategy == "summa"
@@ -227,7 +227,7 @@ def test_build_plan_profile_in_cache_key():
     mesh = fake_mesh((4, 4), ("x", "y"))
     plan_cache.clear()
     latency = MachineProfile(
-        platform="synth", peak_flops=1e18,
+        device_kind="synth", peak_flops=1e18,
         links=(("ici", LinkParams(1.0, 1e18)),))
     p_analytic = build_plan(4096, 4096, 4096, mesh=mesh)
     p_latency = build_plan(4096, 4096, 4096, mesh=mesh, profile=latency)
@@ -281,12 +281,13 @@ from repro import obs
 from repro.plan import build_plan
 from repro.plan.lower_shard_map import _lower_shard_map
 from repro.verify.interceptor import intercept
+from repro.mesh import make_mesh
 from repro.verify.trace import trace_plan
 
 devs = np.array(jax.devices())
-mesh22 = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
-mesh1d = jax.make_mesh((4,), ("t",), devices=devs[:4])
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
+mesh22 = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+mesh1d = make_mesh((4,), ("t",), devices=devs[:4])
+mesh3 = make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
 cells = [("cannon", mesh22), ("summa", mesh22), ("ring_ag", mesh1d),
          ("ring_rs", mesh1d), ("cannon25d", mesh3), ("pod25d", mesh3)]
 

@@ -98,7 +98,7 @@ def test_latency_profile_ranking_flip_capability_derived():
     mesh = fake_mesh((4, 4), ("x", "y"))
     m = n = k = 4096
     prof = MachineProfile(
-        platform="synth", peak_flops=2.86e9,  # compute ~= 3.0 s/device
+        device_kind="synth", peak_flops=2.86e9,  # compute ~= 3.0 s/device
         links=(("ici", LinkParams(1.0, 1e18)),))
     ranked = rank_mesh_strategies(m, n, k, mesh, profile=prof)
     assert ranked[0].strategy == "summa"
@@ -238,7 +238,7 @@ def test_per_axis_profile_prices_each_axis():
 
     def prof(x_link, y_link):
         return MachineProfile(
-            platform="synth", peak_flops=1e18,
+            device_kind="synth", peak_flops=1e18,
             links=(("axis:x", x_link), ("axis:y", y_link),
                    ("ici", LinkParams(0.0, 1e12))))
 
@@ -247,7 +247,7 @@ def test_per_axis_profile_prices_each_axis():
     assert slow_y > slow_x
     # missing axis classes fall back to the pooled link: analytic identity
     pooled = MachineProfile(
-        platform="synth", peak_flops=1e18,
+        device_kind="synth", peak_flops=1e18,
         links=(("ici", LinkParams(0.0, 1e9)),))
     expected = max(2.0 * summa.m * summa.n * summa.k / summa.tp / 1e18,
                    summa.comm_bytes / 1e9)
@@ -333,11 +333,12 @@ from repro import obs
 from repro.plan import build_plan
 from repro.plan.lower_shard_map import _lower_shard_map
 from repro.verify.conformance import check, compare_records
+from repro.mesh import make_mesh
 from repro.verify.trace import trace_plan
 
 devs = np.array(jax.devices())
-mesh44 = jax.make_mesh((4, 4), ("x", "y"), devices=devs[:16])
-mesh24 = jax.make_mesh((2, 4), ("x", "y"), devices=devs[:8])
+mesh44 = make_mesh((4, 4), ("x", "y"), devices=devs[:16])
+mesh24 = make_mesh((2, 4), ("x", "y"), devices=devs[:8])
 rng = np.random.default_rng(0)
 a = jnp.asarray(rng.standard_normal((48, 32)), jnp.float32)
 b = jnp.asarray(rng.standard_normal((32, 40)), jnp.float32)

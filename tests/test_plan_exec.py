@@ -35,11 +35,12 @@ from repro.dist import (cannon_matmul, summa_matmul, pod25d_matmul,
                         cannon25d_matmul, symmetric_matmul)
 from repro import plan as planlib
 from repro.plan import build_plan, execute_plan, lower_shard_map
+from repro.mesh import make_mesh
 
 devs = np.array(jax.devices())
-mesh22 = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
-mesh1d = jax.make_mesh((4,), ("t",), devices=devs[:4])
-mesh3 = jax.make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
+mesh22 = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+mesh1d = make_mesh((4,), ("t",), devices=devs[:4])
+mesh3 = make_mesh((2, 2, 2), ("pod", "x", "y"), devices=devs[:8])
 
 M, K, N = 32, 24, 16
 a = jax.random.normal(jax.random.PRNGKey(0), (M, K), jnp.float32)

@@ -11,10 +11,11 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.layers.ring_blocks import ring_mlp, gspmd_mlp_reference
+from repro.mesh import make_mesh
 from repro.roofline.hlo_stats import analyze
 
 devs = np.array(jax.devices())
-mesh = jax.make_mesh((4,), ("model",), devices=devs)
+mesh = make_mesh((4,), ("model",), devices=devs)
 B, S, D, F = 2, 32, 16, 48
 key = jax.random.PRNGKey(0)
 x = jax.random.normal(key, (B, S, D), jnp.float32)

@@ -92,7 +92,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.roofline.hlo_stats import analyze
-mesh = jax.make_mesh((4,), ("d",))
+from repro.mesh import make_mesh
+mesh = make_mesh((4,), ("d",))
 f = jax.shard_map(lambda x: jax.lax.psum(x, "d"), mesh=mesh,
                   in_specs=P("d"), out_specs=P())
 comp = jax.jit(f).lower(jax.ShapeDtypeStruct((64, 32), jnp.float32)).compile()

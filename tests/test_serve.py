@@ -307,9 +307,10 @@ from repro.models.registry import build_model
 from repro.plan import cache_info
 from repro.runtime.serve import ServeConfig, batch_requests, generate
 from repro.serve import Server, warmup
+from repro.mesh import make_mesh
 
 devs = jax.devices()
-mesh = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+mesh = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
 cfg = dataclasses.replace(get_smoke_config("llama3_2_1b"), dtype="float32")
 model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))

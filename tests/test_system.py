@@ -77,7 +77,8 @@ def test_elastic_remesh_state_roundtrip():
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.runtime.elastic import make_mesh, shrink_after_failure, replace_state
+from repro.mesh import make_mesh
+from repro.runtime.elastic import shrink_after_failure, replace_state
 state = {
     "step": jnp.int32(7),
     "master": {"wq": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)},
@@ -99,3 +100,72 @@ print("ELASTIC_OK")
     res = subprocess.run([sys.executable, "-c", script], capture_output=True,
                          text=True, env=env, timeout=590)
     assert "ELASTIC_OK" in res.stdout, res.stdout + res.stderr
+
+
+def test_chip_smoke_refuses_cpu():
+    """Without a TPU the bring-up script fails and prints no result."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"
+    res = subprocess.run(
+        [sys.executable, os.path.join(_ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, env=env, timeout=300, cwd=_ROOT,
+    )
+    assert res.returncode != 0
+    assert '"ok": true' not in res.stdout
+    assert "needs a TPU" in res.stderr
+
+
+def test_chip_smoke_admitted_plans():
+    """The four-chip phase's admission table matches what the planner
+    admits, so the phase fails only on a refusal nobody expected."""
+    script = r"""
+import chip_smoke, jax
+plans = chip_smoke.admitted_plans(jax.devices(), 4096, 3840, 10240)
+print("ADMITTED", len(plans))
+"""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = _ROOT
+    res = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                         text=True, env=env, timeout=300, cwd=_ROOT)
+    assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-2000:]
+    assert "ADMITTED 60" in res.stdout
+
+
+def test_serve_launcher_writes_compile_cache(tmp_path):
+    """The serving launcher on the CPU, with its compile cache placed from
+    outside: the cache lands in JAX_COMPILATION_CACHE_DIR."""
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    # CPU compiles of a smoke config take well under JAX's 1 s default
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    res = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--smoke",
+         "--arch", "h2o-danube-3-4b", "--buckets", "4x16", "--batch", "3",
+         "--max-new", "4", "--max-seq", "64"],
+        capture_output=True, text=True, env=env, timeout=300, cwd=_ROOT,
+    )
+    assert res.returncode == 0, res.stdout[-2000:] + res.stderr[-2000:]
+    assert "bucket=4x16" in res.stdout
+    assert "12 tokens" in res.stdout
+    assert any((tmp_path / "cache").iterdir())
+
+
+def test_enable_compile_cache_placement(monkeypatch):
+    from repro import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+        assert compile_cache.enable_compile_cache() == "/elsewhere"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable_compile_cache()
+        assert path == os.path.join(_ROOT, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

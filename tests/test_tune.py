@@ -26,6 +26,7 @@ from hypothesis import given, settings, strategies as st
 
 from repro import obs
 from repro.kernels.matmul.kernel import vmem_working_set_bytes
+from repro.mesh import make_mesh
 from repro.obs.profile import MachineProfile, default_profile
 from repro.plan import build_plan, rank_mesh_strategies
 from repro.tune import (MXU, TunedBlocks, TuningTable, Tuner,
@@ -183,7 +184,7 @@ def _mesh(shape, names, need):
     devs = jax.devices()
     if len(devs) < need:
         pytest.skip(f"needs {need} forced-host devices, have {len(devs)}")
-    return jax.make_mesh(shape, names, devices=devs[:need])
+    return make_mesh(shape, names, devices=devs[:need])
 
 
 class TestPlannerWiring:
@@ -299,13 +300,14 @@ import dataclasses, os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 from repro.configs import get_smoke_config
+from repro.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.runtime.serve import ServeConfig
 from repro.serve import warmup
 from repro.tune import Tuner
 
 devs = jax.devices()
-mesh = jax.make_mesh((2, 2), ("x", "y"), devices=devs[:4])
+mesh = make_mesh((2, 2), ("x", "y"), devices=devs[:4])
 cfg = dataclasses.replace(get_smoke_config("llama3_2_1b"), dtype="float32")
 model = build_model(cfg)
 params = model.init(jax.random.PRNGKey(0))
