@@ -4,24 +4,19 @@ Handles arbitrary shapes by padding to block multiples, chooses VMEM-fitting
 MXU-aligned blocks, and falls back to the jnp oracle for shapes too small to
 tile (the kernel is a throughput kernel; tiny matmuls belong to XLA).
 
-With ``repro.obs`` tracing enabled, eager (non-traced) calls are wrapped in
-a ``kernel.matmul`` span: wall time (block_until_ready'd) lands in the
-``kernel.matmul.us`` histogram and achieved FLOPs are recorded against the
-output device's published bf16 peak (``kernel.matmul.roofline_fraction``;
-only for a ``device_kind`` in ``repro.core.cost.DEVICE_PEAKS``).  Disabled mode and
-calls under tracing (tracer operands inside shard_map/jit bodies) go
-straight to the jit'd kernel with zero added work.
+With ``repro.obs`` recording enabled, eager (non-traced) calls of tileable
+shapes record the ragged-shape padding overhead in the ``kernel.pad_waste``
+histogram (padded FLOPs / useful FLOPs).  Disabled mode and calls under
+tracing (tracer operands inside shard_map/jit bodies) add no work.
 """
 from __future__ import annotations
 
 import functools
-import time
 
 import jax
 import jax.numpy as jnp
 
 from repro import obs
-from repro.core.cost import DEVICE_PEAKS
 
 from .kernel import default_blocks, zorder_matmul
 from .ref import matmul_ref
@@ -52,25 +47,14 @@ def matmul(
     out_dtype=None,
 ) -> jax.Array:
     """Z-order Pallas matmul (see module docstring); obs-instrumented."""
-    kw = dict(block_m=block_m, block_n=block_n, block_k=block_k,
-              order=order, interpret=interpret, out_dtype=out_dtype)
+    out = _matmul_jit(a, b, block_m=block_m, block_n=block_n,
+                      block_k=block_k, order=order, interpret=interpret,
+                      out_dtype=out_dtype)
     if not obs.enabled() or isinstance(a, jax.core.Tracer) \
             or isinstance(b, jax.core.Tracer):
-        return _matmul_jit(a, b, **kw)
+        return out
     m, k = a.shape
     n = b.shape[1]
-    with obs.span("kernel.matmul", m=m, n=n, k=k, order=order):
-        t0 = time.perf_counter()
-        out = _matmul_jit(a, b, **kw)
-        jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
-    flops = 2.0 * m * n * k
-    obs.histogram("kernel.matmul.us").observe(dt * 1e6)
-    obs.counter("kernel.matmul.flops").inc(flops)
-    peaks = DEVICE_PEAKS.get(next(iter(out.devices())).device_kind)
-    if peaks is not None:
-        obs.histogram("kernel.matmul.roofline_fraction").observe(
-            flops / dt / peaks.bf16_flops)
     if min(m, n, k) >= _MIN_TILE:
         # ragged shapes are padded to block multiples silently inside the
         # jit; surface the overhead as padded FLOPs / useful FLOPs

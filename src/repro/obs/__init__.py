@@ -1,12 +1,14 @@
-"""repro.obs -- zero-dependency observability: spans, metrics, profiles.
+"""repro.obs -- observability: spans, metrics, profiles.
 
 The measured leg of the repo's measured-vs-analytic loop.  ``repro.verify``
 (PR 3) proves the lowered programs move exactly the analytic number of
 words; this package measures what the machine actually does with them:
 
   runtime    -- hierarchical span tracing (``span("plan.build")``),
-                context-propagated tags, a process-global recorder, and a
-                no-op fast path when disabled (the default)
+                context-propagated tags, a process-global recorder, spans
+                written into the ``jax.profiler`` trace while a profiler
+                session records, and a no-op fast path otherwise (the
+                default)
   metrics    -- typed counters/histograms (plan-cache hits, per-strategy
                 collective counts/bytes, kernel wall-time)
   export     -- Chrome/Perfetto ``trace_event`` JSON + the flat metrics
@@ -21,8 +23,9 @@ words; this package measures what the machine actually does with them:
                 a profile (re-exported as ``repro.launch.perf_probe``'s
                 library entry point)
 
-Nothing here imports jax at module scope; enabling tracing costs one
-module-global check per instrumentation site when off.
+Nothing here imports jax at module scope (the first span imports
+``jax.profiler``); with the recorder off and no profiler session, a span
+costs one module-global check and one profiler-session check.
 """
 from . import calibrate, export, metrics, profile, runtime
 from .calibrate import probe_links

@@ -14,11 +14,17 @@ The process-global :class:`Recorder` collects three event kinds:
                   bitwise-comparable to the conformance interceptor's.
   instants     -- point annotations (cache hits, ranking decisions).
 
-Disabled mode (the default) is a no-op fast path: ``span()`` returns a
-shared singleton context manager that allocates nothing, and every
-instrumentation site guards on ``enabled()`` (one module-global read)
-before touching the recorder.  ``observe()`` is the scoped enable used by
-tests, drift checks, and the benchmark driver.
+Spans also reach the profiler's trace: while a ``jax.profiler`` session
+records, every span opens a ``jax.profiler.TraceAnnotation`` under its own
+name, with its args as the event's stats, whether or not the recorder is
+on.  A host span then sits on the same clock as the device's operations,
+so a trace can say what the host program was doing while a chip idled.
+
+Disabled mode (the default) with no profiler session recording is a no-op
+fast path: ``span()`` returns a shared singleton context manager that
+allocates nothing, and every instrumentation site guards on ``enabled()``
+(one module-global read) before touching the recorder.  ``observe()`` is
+the scoped enable used by tests, drift checks, and ``benchmarks/run.py``.
 """
 from __future__ import annotations
 
@@ -31,6 +37,9 @@ from typing import Any, Dict, List, Optional, Tuple
 Perm = Tuple[Tuple[int, int], ...]
 
 _ENABLED = False
+# jax.profiler.TraceAnnotation, imported by the first span so that
+# importing this module imports no jax
+_ANNOTATION = None
 
 
 def enabled() -> bool:
@@ -167,24 +176,38 @@ def current_tags() -> Dict[str, Any]:
 
 
 class _Span:
-    """Active span handle; re-entrant per ``with`` (one handle per enter)."""
+    """Active span handle; re-entrant per ``with`` (one handle per enter).
+    ``annotation`` is the profiler annotation opened beside the record
+    while a profiler session records, else None."""
 
-    __slots__ = ("name", "args")
+    __slots__ = ("name", "args", "annotation")
 
-    def __init__(self, name: str, args: Dict[str, Any]):
+    def __init__(self, name: str, args: Dict[str, Any], annotation=None):
         self.name = name
         self.args = args
+        self.annotation = annotation
 
     def __enter__(self):
         _stack().append((self.name, _now_us(), self.args))
+        if self.annotation is not None:
+            self.annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
+        if self.annotation is not None:
+            self.annotation.__exit__(*exc)
         name, t0, args = _stack().pop()
         _RECORDER.add_span(SpanRecord(
             name=name, ts_us=t0, dur_us=_now_us() - t0,
             tid=threading.get_ident(), depth=len(_stack()), args=args))
         return False
+
+    def set_metadata(self, **args) -> None:
+        """Add args known only inside the span (the same call as on a
+        ``jax.profiler.TraceAnnotation``)."""
+        self.args.update(args)
+        if self.annotation is not None:
+            self.annotation.set_metadata(**args)
 
 
 class _NoopSpan:
@@ -198,20 +221,41 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def set_metadata(self, **args) -> None:
+        pass
+
 
 NOOP_SPAN = _NoopSpan()
+
+
+def _annotation():
+    global _ANNOTATION
+    from jax.profiler import TraceAnnotation
+
+    _ANNOTATION = TraceAnnotation
+    return TraceAnnotation
 
 
 def span(name: str, **args):
     """Context manager recording one hierarchical span.
 
-        with obs.span("plan.build", strategy="cannon"):
+        with obs.span("plan.build", strategy="cannon") as s:
             ...
+            s.set_metadata(plans=3)      # args known only inside
 
     Args become the span's Perfetto ``args`` and are inherited as ambient
-    tags by everything recorded inside (see ``current_tags``).  When
-    recording is disabled this returns a shared no-op singleton.
+    tags by everything recorded inside (see ``current_tags``).  While a
+    profiler session records, the span is also a
+    ``jax.profiler.TraceAnnotation`` named ``name`` with ``args`` as its
+    stats (give them host ints, strings or bools).  With recording
+    disabled and no profiler session this returns a shared no-op
+    singleton.
     """
+    annotation = _ANNOTATION or _annotation()
+    if annotation.is_enabled():
+        if not _ENABLED:
+            return annotation(name, **args)
+        return _Span(name, args, annotation(name, **args))
     if not _ENABLED:
         return NOOP_SPAN
     return _Span(name, args)

@@ -19,10 +19,18 @@ the process and AOT-warms them over a declared (batch, seq) bucket grid:
     compiled functions.  Per-token wall latencies and TTFT are measured
     around the blocking device calls.
 
-Observability: ``serve.prefill`` / ``serve.decode_step`` spans,
-``serve.ttft_us`` / ``serve.decode_token_us`` histograms, and
-``serve.requests`` / ``serve.tokens`` / ``serve.cold_bucket`` /
-``serve.plan_repin`` counters (all guarded on ``obs.enabled()``).
+Observability: ``obs`` spans at every phase, which reach the profiler's
+trace whenever a ``jax.profiler`` session records.  ``serve.warmup``
+(``bucket``, ``plans``) per warmed bucket; per ``generate`` call,
+``serve.generate`` (``requests``, ``bucket``: a label or ``"cold"``)
+around ``serve.prepare`` (routing, the plan probe, batching, the token
+upload, the cache; ``repinned``: plans re-pinned by the probe),
+``serve.prefill`` (``batch``, ``seq``, ``tokens``: the real prompt
+tokens), ``serve.first_token``, ``serve.decode`` (the loop: one
+``serve.key_split`` and one ``serve.decode_step`` per token after the
+first) and ``serve.collect``.  Span args are host values: no span syncs
+the device or moves data.  ``ServeResult`` carries the latencies and
+counts.
 """
 from __future__ import annotations
 
@@ -131,13 +139,13 @@ class Server:
         report: Dict[str, Dict] = {}
         for bucket in buckets:
             t0 = time.perf_counter()
-            n_plans = self._warm_bucket(bucket)
+            with obs.span("serve.warmup", bucket=bucket.label) as span:
+                n_plans = self._warm_bucket(bucket)
+                span.set_metadata(plans=n_plans)
             report[bucket.label] = {
                 "plans": n_plans,
                 "warm_s": time.perf_counter() - t0,
             }
-        if obs.enabled():
-            obs.counter("serve.warmup.buckets").inc(len(buckets))
         self._warm_cache_info = plan_cache.info()
         if self.tuning is not None and hasattr(self.tuning, "stats"):
             self._warm_tune_stats = dict(self.tuning.stats)
@@ -157,19 +165,18 @@ class Server:
                    if self._uses_offsets else None)
         key = jax.random.PRNGKey(0)
         with planned_scope(self.mesh, self.strategy, self.tuning):
-            with obs.span("serve.warmup", bucket=bucket.label):
-                logits, cache = self._call_prefill(cache, toks, offsets)
-                # two steps, not one: step 2's inputs carry the shardings
-                # step 1's outputs committed them to, a different jit
-                # signature than the fresh init_cache warmup step -- one
-                # step would leave serving to compile that steady state
-                # mid-decode
-                for i in range(min(2, self.cfg.max_new_tokens)):
-                    cur = _sample(logits, self.cfg, key)
-                    logits, cache = self._call_step(
-                        cache, cur[:, None], jnp.int32(bucket.seq + i),
-                        offsets)
-                jax.block_until_ready(logits)
+            logits, cache = self._call_prefill(cache, toks, offsets)
+            # two steps, not one: step 2's inputs carry the shardings
+            # step 1's outputs committed them to, a different jit
+            # signature than the fresh init_cache warmup step -- one
+            # step would leave serving to compile that steady state
+            # mid-decode
+            for i in range(min(2, self.cfg.max_new_tokens)):
+                cur = _sample(logits, self.cfg, key)
+                logits, cache = self._call_step(
+                    cache, cur[:, None], jnp.int32(bucket.seq + i),
+                    offsets)
+            jax.block_until_ready(logits)
         new_keys = [k for k in plan_cache.keys() if k not in before]
         snapshot = {k: plan_cache.get(k) for k in new_keys}
         # a later bucket can share plans with an earlier one (same decode
@@ -192,82 +199,86 @@ class Server:
         if not prompt_list:
             return ServeResult([], [], None, 0.0, np.zeros(0), 0.0,
                                {"probed": 0, "missing": 0})
-        t_start = time.perf_counter()
         n = len(prompt_list)
-        maxlen = max(len(p) for p in prompt_list)
-        bucket = route(n, maxlen, self.buckets)
-        if bucket is not None and not self._uses_offsets \
-                and bucket.seq != maxlen:
-            # seq-padding shifts tokens through a recurrent state; only
-            # batch-pad for models without position-offset support
-            bucket = Bucket(bucket.batch, maxlen) \
-                if bucket.batch >= n else None
-        probe = self._probe_bucket(bucket)
+        with obs.span("serve.generate", requests=n) as call:
+            t_start = time.perf_counter()
+            with obs.span("serve.prepare") as prep:
+                maxlen = max(len(p) for p in prompt_list)
+                bucket = route(n, maxlen, self.buckets)
+                if bucket is not None and not self._uses_offsets \
+                        and bucket.seq != maxlen:
+                    # seq-padding shifts tokens through a recurrent state;
+                    # only batch-pad for models without position-offset
+                    # support
+                    bucket = Bucket(bucket.batch, maxlen) \
+                        if bucket.batch >= n else None
+                probe = self._probe_bucket(bucket)
+                call.set_metadata(bucket=bucket.label if bucket else "cold")
+                prep.set_metadata(repinned=probe["missing"])
 
-        if bucket is None:
-            if obs.enabled():
-                obs.counter("serve.cold_bucket").inc()
-            batch, lens = batch_requests(prompt_list, self.pad_id)
-            b_rows = n
-        else:
-            dummies = [[self.dummy_token]] * (bucket.batch - n)
-            batch, lens = batch_requests(
-                list(prompt_list) + dummies, self.pad_id, pad_to=bucket.seq)
-            b_rows = bucket.batch
-        self.cfg.validate_prompt_len(batch.shape[1])
+                if bucket is None:
+                    batch, lens = batch_requests(prompt_list, self.pad_id)
+                    b_rows = n
+                else:
+                    dummies = [[self.dummy_token]] * (bucket.batch - n)
+                    batch, lens = batch_requests(
+                        list(prompt_list) + dummies, self.pad_id,
+                        pad_to=bucket.seq)
+                    b_rows = bucket.batch
+                self.cfg.validate_prompt_len(batch.shape[1])
 
-        key = key if key is not None else jax.random.PRNGKey(0)
-        tokens = jnp.asarray(batch, jnp.int32)
-        sp = tokens.shape[1]
-        offsets = (jnp.asarray(sp - lens, jnp.int32)
-                   if self._uses_offsets else None)
-        cache = self.model.init_cache(b_rows, self.cfg.max_seq)
+                key = key if key is not None else jax.random.PRNGKey(0)
+                tokens = jnp.asarray(batch, jnp.int32)
+                sp = tokens.shape[1]
+                offsets = (jnp.asarray(sp - lens, jnp.int32)
+                           if self._uses_offsets else None)
+                cache = self.model.init_cache(b_rows, self.cfg.max_seq)
 
-        out = [tokens]
-        step_lat: List[float] = []
-        with planned_scope(self.mesh, self.strategy, self.tuning):
-            with obs.span("serve.prefill", batch=b_rows, seq=sp):
-                logits, cache = self._call_prefill(cache, tokens, offsets)
-            prefill_logits = logits
-            if self.cfg.max_new_tokens > 0:
-                cur = _sample(logits, self.cfg, key)
-                jax.block_until_ready(cur)
-                ttft = time.perf_counter() - t_start
-                out.append(cur[:, None])
-                for t in range(sp, sp + self.cfg.max_new_tokens - 1):
-                    key, sub = jax.random.split(key)
-                    t0 = time.perf_counter()
-                    with obs.span("serve.decode_step", batch=b_rows, pos=t):
-                        logits, cache = self._call_step(
-                            cache, cur[:, None], jnp.int32(t), offsets)
-                        cur = _sample(logits, self.cfg, sub)
+            out = [tokens]
+            step_lat: List[float] = []
+            with planned_scope(self.mesh, self.strategy, self.tuning):
+                with obs.span("serve.prefill", batch=b_rows, seq=sp,
+                              tokens=sum(len(p) for p in prompt_list)):
+                    logits, cache = self._call_prefill(cache, tokens,
+                                                       offsets)
+                prefill_logits = logits
+                if self.cfg.max_new_tokens > 0:
+                    with obs.span("serve.first_token"):
+                        cur = _sample(logits, self.cfg, key)
                         jax.block_until_ready(cur)
-                    step_lat.append(time.perf_counter() - t0)
+                    ttft = time.perf_counter() - t_start
                     out.append(cur[:, None])
-            else:
-                jax.block_until_ready(logits)
-                ttft = time.perf_counter() - t_start
-        full = np.asarray(jnp.concatenate(out, axis=1))
-        wall = time.perf_counter() - t_start
-
-        sequences, new_tokens = [], []
-        for i in range(n):
-            row = full[i]
-            seq = row[sp - int(lens[i]):].tolist()   # strip left padding
-            sequences.append(seq)
-            new_tokens.append(seq[int(lens[i]):])
-        if obs.enabled():
-            obs.counter("serve.requests").inc(
-                n, bucket=bucket.label if bucket else "cold")
-            obs.counter("serve.tokens").inc(sum(len(t) for t in new_tokens))
-            obs.histogram("serve.ttft_us").observe(ttft * 1e6)
-            h = obs.histogram("serve.decode_token_us")
-            for dt in step_lat:
-                h.observe(dt * 1e6)
+                    with obs.span("serve.decode"):
+                        for t in range(sp, sp + self.cfg.max_new_tokens - 1):
+                            with obs.span("serve.key_split"):
+                                key, sub = jax.random.split(key)
+                            t0 = time.perf_counter()
+                            with obs.span("serve.decode_step", batch=b_rows,
+                                          pos=t):
+                                logits, cache = self._call_step(
+                                    cache, cur[:, None], jnp.int32(t),
+                                    offsets)
+                                cur = _sample(logits, self.cfg, sub)
+                                jax.block_until_ready(cur)
+                            step_lat.append(time.perf_counter() - t0)
+                            out.append(cur[:, None])
+                else:
+                    jax.block_until_ready(logits)
+                    ttft = time.perf_counter() - t_start
+            with obs.span("serve.collect"):
+                full = np.asarray(jnp.concatenate(out, axis=1))
+                wall = time.perf_counter() - t_start
+                sequences, new_tokens = [], []
+                for i in range(n):
+                    row = full[i]
+                    seq = row[sp - int(lens[i]):].tolist()  # strip left pad
+                    sequences.append(seq)
+                    new_tokens.append(seq[int(lens[i]):])
+                prefill_logits = prefill_logits[:n]
         return ServeResult(sequences, new_tokens,
                            bucket.label if bucket else None,
                            ttft, np.asarray(step_lat), wall, probe,
-                           prefill_logits[:n])
+                           prefill_logits)
 
     # -- plan-cache accounting -----------------------------------------------
 
@@ -282,8 +293,6 @@ class Server:
         for k in missing:
             if snapshot[k] is not None:
                 plan_cache.put(k, snapshot[k])
-        if missing and obs.enabled():
-            obs.counter("serve.plan_repin").inc(len(missing))
         out = {"probed": len(snapshot), "missing": len(missing)}
         if self.tuning is not None and hasattr(self.tuning, "lookup_key"):
             tune_keys = self._bucket_tune_keys.get(bucket, ())

@@ -33,3 +33,25 @@ def _reset_plan_cache():
 
     cache_clear()
     yield
+
+
+@pytest.fixture
+def xplane_host_events():
+    """Reader of the host events a ``jax.profiler.trace(trace_dir)`` wrote:
+    ``read(trace_dir)`` -> ``[(name, start_ns, end_ns, args)]`` in start
+    order, ``args`` being the event's stats (a span's keyword args)."""
+    import glob
+
+    def read(trace_dir):
+        from jax.profiler import ProfileData
+
+        (path,) = glob.glob(os.path.join(
+            str(trace_dir), "plugins", "profile", "*", "*.xplane.pb"))
+        events = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
+                   dict(ev.stats))
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name == "/host:CPU"
+                  for line in plane.lines for ev in line.events]
+        return sorted(events, key=lambda e: e[1])
+
+    return read

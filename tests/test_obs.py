@@ -5,6 +5,9 @@ Pins the PR-6 acceptance criteria:
   * span nesting + Perfetto trace_event export round-trips (schema keys,
     JSON-serializable, nesting depths);
   * disabled mode is a true no-op (shared singleton span, empty recorder);
+  * spans reach a ``jax.profiler`` trace under their own names, args as
+    stats, whenever a profiler session records, with or without the
+    recorder;
   * the obs collective multiset equals the ``repro.verify`` interceptor's
     AND the schedule trace's, per strategy, on real executions (subprocess
     with forced-host devices);
@@ -14,6 +17,7 @@ Pins the PR-6 acceptance criteria:
   * profile JSON round-trip + newer-schema rejection, α–β fit recovery;
   * plan-cache ``cache_info()`` size/eviction accounting.
 """
+import contextlib
 import json
 import math
 import os
@@ -76,6 +80,37 @@ def test_span_nesting_and_tags():
     assert outer.dur_us >= inner.dur_us >= 0
     # observe() restored the previous (disabled) state
     assert not obs.enabled()
+
+
+@pytest.mark.parametrize("recording", [False, True])
+@pytest.mark.parametrize("profiling", [False, True])
+def test_span_reaches_profiler_trace_and_recorder(recording, profiling,
+                                                  tmp_path,
+                                                  xplane_host_events):
+    import jax
+
+    n_before = len(obs.get_recorder().spans)
+    session = (jax.profiler.trace(str(tmp_path)) if profiling
+               else contextlib.nullcontext())
+    scope = obs.observe() if recording else contextlib.nullcontext()
+    with scope as rec, session:
+        span = obs.span("serve.batch", rows=8, label="8x512")
+        if not (recording or profiling):
+            assert span is obs.NOOP_SPAN
+        with span as handle:
+            handle.set_metadata(tokens=512)
+    args = {"rows": 8, "label": "8x512", "tokens": 512}
+    if recording:
+        (record,) = rec.spans
+        assert record.name == "serve.batch" and record.args == args
+    else:
+        assert len(obs.get_recorder().spans) == n_before
+    if profiling:
+        found = [(name, stats) for name, _, _, stats
+                 in xplane_host_events(tmp_path) if name == "serve.batch"]
+        assert found == [("serve.batch", args)]
+    else:
+        assert not list(tmp_path.iterdir())
 
 
 def test_collective_events_carry_strategy_and_key():

@@ -13,6 +13,7 @@ import json
 import os
 import subprocess
 import sys
+from collections import Counter
 
 import jax
 import numpy as np
@@ -218,6 +219,42 @@ def test_server_empty_cold_and_null_latency(llama):
     r0 = zero.generate([[5, 6, 7]])
     assert r0.new_tokens == [[]]
     assert r0.latency_quantiles_ms() == {"p50_ms": None, "p99_ms": None}
+
+
+def test_server_generate_spans_on_profiler_clock(llama, tmp_path,
+                                                 xplane_host_events):
+    """Every phase of ``generate`` is a host span in the profiler's trace,
+    nested under ``serve.generate``, with host-value args."""
+    _, model, params = llama
+    new = 4
+    srv = Server(model, params, ServeConfig(max_new_tokens=new, max_seq=32),
+                 buckets=[(4, 8)])
+    srv.warmup()
+    prompts = [[5, 6, 7], [9, 2, 3, 4, 1]]
+    with jax.profiler.trace(str(tmp_path)):
+        srv.generate(prompts)
+    spans = [e for e in xplane_host_events(tmp_path)
+             if e[0].startswith("serve.")]
+    assert Counter(name for name, *_ in spans) == {
+        "serve.generate": 1, "serve.prepare": 1, "serve.prefill": 1,
+        "serve.first_token": 1, "serve.decode": 1, "serve.collect": 1,
+        "serve.key_split": new - 1, "serve.decode_step": new - 1}
+    by_name = {name: (s, e, args) for name, s, e, args in spans}
+    g0, g1, gen_args = by_name["serve.generate"]
+    assert gen_args == {"requests": 2, "bucket": "4x8"}
+    assert all(g0 <= s <= e <= g1 for _, s, e, _ in spans)
+    phases = ["serve.prepare", "serve.prefill", "serve.first_token",
+              "serve.decode", "serve.collect"]
+    ends = [by_name[p][:2] for p in phases]
+    assert all(a[1] <= b[0] for a, b in zip(ends, ends[1:]))   # in order
+    d0, d1, _ = by_name["serve.decode"]
+    loop = [name for name, s, e, _ in spans
+            if name in ("serve.key_split", "serve.decode_step")
+            and d0 <= s <= e <= d1]
+    assert loop == ["serve.key_split", "serve.decode_step"] * (new - 1)
+    assert by_name["serve.prepare"][2] == {"repinned": 0}
+    assert by_name["serve.prefill"][2] == {
+        "batch": 4, "seq": 8, "tokens": sum(len(p) for p in prompts)}
 
 
 def test_warmup_helper_returns_warm_server(llama):
