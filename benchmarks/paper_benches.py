@@ -519,8 +519,10 @@ def bench_tuned_vs_default() -> List[Row]:
     and the MoE expert GEMM from ``configs/deepseek_moe_16b`` (per-token
     expert d_model x moe_d_ff, clamped for CI).  The searched winner must
     not lose to the heuristic beyond the ``TUNE_DRIFT_MARGIN`` noise
-    margin (default 10%) -- the search space contains the heuristic's own
-    blocks, so a regression means the measurement harness lies."""
+    margin (default 10%).  On the square and expert shapes the search
+    space contains the heuristic's own blocks, so a regression there means
+    the measurement harness lies; on the ragged one the heuristic takes
+    m = 384 whole, which the tuner's power-of-two bucket pads to 512."""
     import jax
     import jax.numpy as jnp
 

@@ -9,8 +9,11 @@ simultaneously.  The contraction axis k stays innermost (contiguous revisits
 of the output block are required for legal accumulation on TPU, and k is the
 "time" axis of the systolic MXU -- the paper's Delta).
 
-Hardware adaptation notes: block shapes are multiples of the 128-wide
-MXU/VREG tiling; the fp32 accumulator lives in a VMEM scratch so
+Hardware adaptation notes: ``default_blocks`` takes each block side from
+the divisors of its dimension that are multiples of the 128-wide MXU/VREG
+tiling (or the whole dimension), so no operand is padded, and sizes the
+output tile past v5e's ridge point, since every grid step fetches a fresh
+A and B block.  The fp32 accumulator lives in a VMEM scratch so
 low-precision inputs (bf16) accumulate at full precision.  The kernel asks
 Mosaic for ``VMEM_LIMIT_BYTES`` of scoped VMEM, and every block choice
 (``default_blocks`` here, ``repro.tune.candidate_space``) keeps its working
@@ -26,6 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.cost import HBM_BW, PEAK_FLOPS_BF16
 from repro.core.zorder import zorder_schedule
 
 # Scoped VMEM the kernel may use.  Mosaic's default scoped limit on v5e is
@@ -131,21 +135,48 @@ def vmem_working_set_bytes(
     return a + b + acc + out
 
 
+# FLOP per byte of HBM traffic at which v5e turns from memory- to
+# compute-bound: 197 TFLOP/s of bf16 over 819 GB/s, about 240.
+RIDGE_FLOP_PER_BYTE = PEAK_FLOPS_BF16 / HBM_BW
+
+
+def _sides(d: int) -> list[int]:
+    """Block sides that tile ``d`` with nothing padded: its divisors that
+    are multiples of 128, or ``d`` whole where it is no multiple of 128."""
+    return [b for b in range(128, d + 1, 128) if d % b == 0] or [d]
+
+
 def default_blocks(m: int, n: int, k: int, dtype_bytes: int = 2,
                    out_dtype_bytes: int | None = None) -> Tuple[int, int, int]:
-    """Pick MXU-aligned blocks that fit VMEM; prefers large k blocks (the
-    contraction reuse direction) then square-ish (m, n)."""
-    bm = min(256, max(128, m))
-    bn = min(256, max(128, n))
-    bk = min(2048, max(128, k))
-    while vmem_working_set_bytes(bm, bn, bk, dtype_bytes,
-                                 out_dtype_bytes) > VMEM_BUDGET_BYTES:
-        if bk > 256:
-            bk //= 2
-        elif bm >= bn and bm > 128:
-            bm //= 2
-        elif bn > 128:
-            bn //= 2
-        else:
-            break
-    return bm, bn, bk
+    """The (block_m, block_n, block_k) that tile an (m, k) x (k, n) GEMM.
+
+    Each block side divides its dimension (``_sides``), so ``ops.matmul``
+    pads nothing; only where no such set fits ``VMEM_BUDGET_BYTES`` (a
+    dimension that is no multiple of 128 and too large to take whole) are
+    the dimensions rounded up to multiples of 128 and padded.  Every grid
+    step fetches an A and a B block, 2 * bm * bn * bk FLOPs against
+    (bm + bn) * bk * dtype_bytes bytes, so the output tile sets the
+    arithmetic intensity.  Of the tiles that fit the budget with some k
+    block, the rule prefers the highest intensity up to v5e's ridge point
+    (``RIDGE_FLOP_PER_BYTE``); past it every tile is compute-bound, and
+    the rule prefers the fewest grid steps, each costing a fixed overhead,
+    then the higher intensity.  ``block_k`` is the largest side of k that
+    fits beside the tile."""
+    out_b = out_dtype_bytes or dtype_bytes
+
+    def best(m, n, k):
+        scored, k_sides = [], _sides(k)
+        for bm in _sides(m):
+            for bn in _sides(n):
+                bks = [bk for bk in k_sides if vmem_working_set_bytes(
+                    bm, bn, bk, dtype_bytes, out_b) <= VMEM_BUDGET_BYTES]
+                if not bks:
+                    continue
+                bk = bks[-1]
+                reuse = 2 * bm * bn / ((bm + bn) * dtype_bytes)
+                steps = (m // bm) * (n // bn) * (k // bk)
+                scored.append(((min(reuse, RIDGE_FLOP_PER_BYTE), -steps,
+                                reuse), (bm, bn, bk)))
+        return max(scored)[1] if scored else None
+
+    return best(m, n, k) or best(*(-(-d // 128) * 128 for d in (m, n, k)))

@@ -1,8 +1,10 @@
 """jit'd public wrapper for the Z-order matmul kernel.
 
-Handles arbitrary shapes by padding to block multiples, chooses VMEM-fitting
-MXU-aligned blocks, and falls back to the jnp oracle for shapes too small to
-tile (the kernel is a throughput kernel; tiny matmuls belong to XLA).
+Chooses VMEM-fitting blocks that divide the shape (``default_blocks``),
+pads operands to block multiples only where the blocks do not divide it
+(explicit or tuned blocks, or no dividing set fits), and falls back to the
+jnp oracle for shapes too small to tile (the kernel is a throughput kernel;
+tiny matmuls belong to XLA).
 
 With ``repro.obs`` recording enabled, eager (non-traced) calls of tileable
 shapes record the ragged-shape padding overhead in the ``kernel.pad_waste``

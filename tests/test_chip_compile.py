@@ -11,6 +11,7 @@ may touch it.  Keep every such compile in this one file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -52,17 +53,25 @@ def _hlo(f, *args) -> str:
     return jax.jit(f).lower(*args).compile().as_text()
 
 
-# (m, k, n, out dtype): h2o-danube-3-4b's MLP up-projection and its k/v
-# projection (960 columns: not a multiple of the default 256-wide block)
+# (m, k, n, out dtype): h2o-danube-3-4b's linears of a 4096-row prefill
+# (the MLP gate/up and down projections, q/o, and k/v, whose 960 columns
+# are no multiple of 128), and granite-20b's Cannon qkv block on a 2x2
+# torus (K = 3072, N = 3200, float32 partial sums)
 @pytest.mark.parametrize("m,k,n,out_dtype", [
     (4096, 3840, 10240, jnp.bfloat16),
     (4096, 3840, 960, jnp.float32),
+    (4096, 3840, 3840, jnp.bfloat16),
+    (4096, 3840, 960, jnp.bfloat16),
+    (4096, 10240, 3840, jnp.bfloat16),
+    (2048, 3072, 3200, jnp.float32),
 ])
 def test_kernel_default_blocks_compile(one_chip, m, k, n, out_dtype):
     a = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
     b = jax.ShapeDtypeStruct((k, n), jnp.bfloat16, sharding=one_chip)
     hlo = _hlo(functools.partial(matmul, out_dtype=out_dtype), a, b)
     assert "tpu_custom_call" in hlo
+    # the default blocks divide these shapes: no operand is padded
+    assert not re.search(r"\spad\(", hlo)
 
 
 def test_cannon_2x2_program_holds_kernel(topo, monkeypatch):

@@ -14,6 +14,31 @@ def _tol(dtype):
     return 2e-2 if dtype == jnp.bfloat16 else 1e-4
 
 
+def _legal(block, dim):
+    """A Pallas TPU block side: a multiple of the 128-wide tiling, or the
+    whole dimension."""
+    return block % 128 == 0 or block == dim
+
+
+# (m, k, n, output bytes) of the benchmark's GEMMs: h2o-danube-3-4b's seven
+# linears of a 4096-row prefill, and granite-20b's local multiplies on a
+# 2x2 torus (Cannon's qkv and o, the ring all-gather's up projection, the
+# ring reduce-scatter's down projection into float32)
+BENCH_GEMMS = [
+    pytest.param(4096, 3840, 3840, 2, id="danube.q"),
+    pytest.param(4096, 3840, 960, 2, id="danube.k"),
+    pytest.param(4096, 3840, 960, 2, id="danube.v"),
+    pytest.param(4096, 3840, 3840, 2, id="danube.o"),
+    pytest.param(4096, 3840, 10240, 2, id="danube.gate"),
+    pytest.param(4096, 3840, 10240, 2, id="danube.up"),
+    pytest.param(4096, 10240, 3840, 2, id="danube.down"),
+    pytest.param(2048, 3072, 3200, 4, id="granite.cannon_qkv"),
+    pytest.param(2048, 3072, 3072, 4, id="granite.cannon_o"),
+    pytest.param(1024, 6144, 6144, 2, id="granite.ring_ag"),
+    pytest.param(4096, 6144, 6144, 4, id="granite.ring_rs"),
+]
+
+
 class TestZOrderMatmul:
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     @pytest.mark.parametrize("shape", [
@@ -38,15 +63,17 @@ class TestZOrderMatmul:
         assert jnp.allclose(out, matmul_ref(a, b), atol=1e-3)
 
     def test_default_blocks_fit_vmem(self):
-        for dims in [(4096, 4096, 4096), (128, 32768, 256), (8192, 512, 8192)]:
+        # (4096, 4096, 50000): k is no multiple of 128 and too large to
+        # take whole, so it is padded to a multiple of 128
+        for dims in [(4096, 4096, 4096), (128, 32768, 256), (8192, 512, 8192),
+                     (4096, 4096, 50000)]:
             bm, bn, bk = default_blocks(*dims)
             assert bm % 128 == 0 and bn % 128 == 0 and bk % 128 == 0
             assert vmem_working_set_bytes(bm, bn, bk) <= VMEM_BUDGET_BYTES
 
     @settings(max_examples=60, deadline=None)
-    @given(dims=st.tuples(st.sampled_from([128, 512, 4096, 32768]),
-                          st.sampled_from([128, 512, 4096, 32768]),
-                          st.sampled_from([128, 512, 4096, 32768])),
+    @given(dims=st.tuples(*[st.sampled_from(
+               [128, 512, 960, 3072, 3200, 3840, 4096, 32768])] * 3),
            dtype_bytes=st.sampled_from([1, 2, 4]),
            out_dtype_bytes=st.sampled_from([2, 4]))
     def test_default_blocks_fit_vmem_any_dtype(self, dims, dtype_bytes,
@@ -55,9 +82,38 @@ class TestZOrderMatmul:
         output byte widths, not the bf16 defaults -- fp32 operands halve
         the feasible block space."""
         bm, bn, bk = default_blocks(*dims, dtype_bytes, out_dtype_bytes)
-        assert bm % 128 == 0 and bn % 128 == 0 and bk % 128 == 0
+        m, n, k = dims
+        assert _legal(bm, m) and _legal(bn, n) and _legal(bk, k)
         assert vmem_working_set_bytes(
             bm, bn, bk, dtype_bytes, out_dtype_bytes) <= VMEM_BUDGET_BYTES
+
+    @pytest.mark.parametrize("m,k,n,out_dtype_bytes", BENCH_GEMMS)
+    def test_default_blocks_divide_bench_gemms(self, m, k, n,
+                                               out_dtype_bytes):
+        """At the benchmark's shapes the default blocks divide the GEMM,
+        so ``_matmul_jit`` pads nothing, and each block is legal and fits
+        the budget."""
+        bm, bn, bk = default_blocks(m, n, k, 2, out_dtype_bytes)
+        assert m % bm == 0 and n % bn == 0 and k % bk == 0
+        assert _legal(bm, m) and _legal(bn, n) and _legal(bk, k)
+        assert vmem_working_set_bytes(
+            bm, bn, bk, 2, out_dtype_bytes) <= VMEM_BUDGET_BYTES
+
+    @pytest.mark.parametrize("out_dtype", [jnp.bfloat16, jnp.float32])
+    def test_whole_dimension_block(self, out_dtype):
+        """n = 192 is no multiple of 128: the default block takes it
+        whole instead of padding it."""
+        m, k, n = 256, 384, 192
+        obytes = jnp.dtype(out_dtype).itemsize
+        assert default_blocks(m, n, k, 2, obytes)[1] == n
+        a = jax.random.normal(jax.random.PRNGKey(6), (m, k), jnp.bfloat16)
+        b = jax.random.normal(jax.random.PRNGKey(7), (k, n), jnp.bfloat16)
+        out = matmul(a, b, interpret=True, out_dtype=out_dtype)
+        ref = matmul_ref(a, b, out_dtype=out_dtype)
+        assert out.shape == (m, n) and out.dtype == out_dtype
+        err = jnp.max(jnp.abs(out.astype(jnp.float32) - ref.astype(jnp.float32)))
+        scale = jnp.max(jnp.abs(ref.astype(jnp.float32)))
+        assert float(err / scale) < _tol(out_dtype)
 
     def test_tiny_fallback(self):
         a = jax.random.normal(jax.random.PRNGKey(4), (8, 16), jnp.float32)
