@@ -14,5 +14,5 @@ SMOKE = ModelConfig(
     name="qwen3-moe-smoke", family="moe",
     num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
     d_ff=96, vocab_size=256, head_dim=16,
-    num_experts=8, top_k=2, moe_d_ff=96, moe_group_size=32, attn_chunk=32,
+    num_experts=8, top_k=2, moe_d_ff=96, attn_chunk=32,
 )

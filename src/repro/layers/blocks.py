@@ -80,7 +80,11 @@ def block_apply(
         if kind == "attn_mlp":
             m = mlp(p["mlp"], h)
         else:
-            m, aux = moe(p["moe"], h, cfg)
+            # a left-padded row's padding slots sit at negative positions:
+            # no expert computes them
+            valid = (jnp.broadcast_to(positions >= 0, h.shape[:2])
+                     if offsets is not None else None)
+            m, aux = moe(p["moe"], h, cfg, valid)
         x = x + constrain(m, "batch", None, None)
         return x, aux, new_cache
     if kind == "mamba":

@@ -32,13 +32,17 @@ class ModelConfig:
     v_head_dim: int = 0
 
     # MoE
-    num_experts: int = 0
+    num_experts: int = 0         # the router's width: experts of a layer
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
-    capacity_factor: float = 1.25
     first_dense_layers: int = 0  # deepseek-moe: leading dense layers
-    moe_group_size: int = 256    # GShard routing-group size
+    norm_topk_prob: bool = True  # renormalise the top-k gates to sum to 1
+    # the routed experts this model holds, ids first_held_expert ..
+    # + experts_held - 1: one chip's share of an expert-parallel
+    # deployment (0: all of them)
+    experts_held: int = 0
+    first_held_expert: int = 0
 
     # SSM / hybrid / xlstm
     ssm_state: int = 0
@@ -67,6 +71,17 @@ class ModelConfig:
     def __post_init__(self):
         if self.head_dim == 0:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        if self.experts_held == 0:
+            object.__setattr__(self, "experts_held", self.num_experts)
+        if self.num_experts and not (
+                0 < self.experts_held
+                and 0 <= self.first_held_expert
+                and self.first_held_expert + self.experts_held
+                <= self.num_experts):
+            raise ValueError(
+                f"held experts {self.first_held_expert}.."
+                f"{self.first_held_expert + self.experts_held - 1} lie "
+                f"outside the router's {self.num_experts}")
 
     @property
     def group_size(self) -> int:
@@ -86,12 +101,14 @@ class ModelConfig:
         return n
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top_k + shared experts only)."""
+        """Parameters touched per token (MoE: the shared experts and at most
+        top_k of the held experts)."""
         if self.num_experts == 0:
             return self.param_count()
         d = self.d_model
         expert = 3 * d * self.moe_d_ff
-        inactive = (self.num_experts - self.top_k) * expert
+        held = self.experts_held
+        inactive = (held - min(self.top_k, held)) * expert
         moe_layers = self.num_layers - self.first_dense_layers
         return self.param_count() - inactive * moe_layers
 
@@ -118,8 +135,8 @@ class ModelConfig:
             return per * self.num_layers
         if self.family == "moe":
             expert = 3 * d * self.moe_d_ff
-            moe = (self.num_experts + self.num_shared_experts) * expert
-            moe += d * self.num_experts  # router
+            moe = (self.experts_held + self.num_shared_experts) * expert
+            moe += d * self.num_experts  # router: every expert's logit
             per_moe = self._attn_params() + moe + 2 * d
             per_dense = self._attn_params() + self._mlp_params(self.d_ff) + 2 * d
             nd = self.first_dense_layers

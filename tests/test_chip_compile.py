@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from repro.kernels.matmul import matmul
+from repro.kernels.matmul.grouped import gmm_pallas
 from repro.kernels.matmul.kernel import vmem_working_set_bytes, zorder_matmul
 from repro.mesh import make_mesh
 from repro.plan import build_plan, lower_shard_map
@@ -107,3 +108,16 @@ def test_largest_fp32_candidate_compiles(one_chip):
                           order=order, out_dtype=jnp.float32)
     x = jax.ShapeDtypeStruct((m, k), jnp.float32, sharding=one_chip)
     assert "tpu_custom_call" in _hlo(f, x, x)
+
+
+# (rows, k, n): deepseek-moe's expert GEMMs over its 8 held experts, at the
+# engine cell's 30720-row dispatch buffer (gate/up, then down) and a decode
+# step's 48 rows
+@pytest.mark.parametrize("m,k,n", [(30720, 2048, 1408), (30720, 1408, 2048),
+                                   (48, 2048, 1408)])
+def test_grouped_kernel_compiles(one_chip, m, k, n):
+    x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    hlo = _hlo(gmm_pallas, x, w, sizes)
+    assert "tpu_custom_call" in hlo and "grouped_matmul" in hlo

@@ -5,7 +5,9 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.kernels.flash_attention import attention_ref, mha
-from repro.kernels.matmul import matmul, matmul_ref, zorder_matmul
+from repro.kernels.matmul import (grouped_matmul, matmul, matmul_ref,
+                                  zorder_matmul)
+from repro.kernels.matmul.grouped import gmm_ragged, grouped_blocks
 from repro.kernels.matmul.kernel import (VMEM_BUDGET_BYTES, default_blocks,
                                          vmem_working_set_bytes)
 
@@ -119,6 +121,78 @@ class TestZOrderMatmul:
         a = jax.random.normal(jax.random.PRNGKey(4), (8, 16), jnp.float32)
         b = jax.random.normal(jax.random.PRNGKey(5), (16, 8), jnp.float32)
         assert jnp.allclose(matmul(a, b), a @ b, atol=1e-5)
+
+
+def _grouped_ref(x, w, sizes):
+    """Each group's rows times its weight in float32; rows past the groups
+    zero."""
+    out = jnp.zeros((x.shape[0], w.shape[2]), jnp.float32)
+    start = 0
+    for g, size in enumerate(sizes):
+        out = out.at[start:start + size].set(jnp.matmul(
+            x[start:start + size].astype(jnp.float32),
+            w[g].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST))
+        start += size
+    return out
+
+
+class TestGroupedMatmul:
+    # (rows, k, n, group sizes): ragged groups, empty groups first, last
+    # and between, rows past the last group, a group spanning several row
+    # tiles, no rows at all, rows no multiple of 16
+    @pytest.mark.parametrize("m,k,n,sizes", [
+        (256, 128, 256, [0, 100, 0, 37, 90, 0]),
+        (48, 128, 192, [3, 0, 2]),
+        (1088, 256, 384, [513, 0, 511, 1]),
+        (300, 256, 128, [0, 0, 0, 0]),
+        (200, 128, 128, [7, 150, 43]),
+    ])
+    @pytest.mark.parametrize("out_dtype", [jnp.bfloat16, jnp.float32])
+    def test_against_float32_product(self, m, k, n, sizes, out_dtype):
+        x = jax.random.normal(jax.random.PRNGKey(0), (m, k)).astype(
+            jnp.bfloat16)
+        w = jax.random.normal(jax.random.PRNGKey(1), (len(sizes), k, n)
+                              ).astype(jnp.bfloat16)
+        s = jnp.asarray(sizes, jnp.int32)
+        want = _grouped_ref(x, w, sizes)
+        scale = float(jnp.max(jnp.abs(want))) or 1.0
+        for out in (grouped_matmul(x, w, s, out_dtype=out_dtype,
+                                   interpret=True),
+                    gmm_ragged(x, w, s, out_dtype=out_dtype)):
+            assert out.shape == (m, n) and out.dtype == out_dtype
+            err = jnp.max(jnp.abs(out.astype(jnp.float32) - want)) / scale
+            assert float(err) < (1e-2 if out_dtype == jnp.bfloat16 else 1e-5)
+        assert not jnp.any(out[sum(sizes):])
+
+    def test_one_compile_for_any_sizes(self):
+        x = jnp.ones((256, 128), jnp.bfloat16)
+        w = jnp.ones((4, 128, 128), jnp.bfloat16)
+        before = grouped_matmul._cache_size()
+        for sizes in ([64, 64, 64, 64], [0, 0, 256, 0], [1, 2, 3, 4]):
+            grouped_matmul(x, w, jnp.asarray(sizes, jnp.int32),
+                           interpret=True)
+        assert grouped_matmul._cache_size() == before + 1
+
+    def test_cotangents_are_the_ragged_dots(self):
+        x = jax.random.normal(jax.random.PRNGKey(2), (96, 128))
+        w = jax.random.normal(jax.random.PRNGKey(3), (3, 128, 128))
+        s = jnp.asarray([40, 0, 50], jnp.int32)
+
+        def loss(f):
+            return lambda x, w: jnp.sum(jnp.sin(f(x, w, s)))
+
+        got = jax.grad(loss(lambda x, w, s: grouped_matmul(
+            x, w, s, interpret=True)), argnums=(0, 1))(x, w)
+        want = jax.grad(loss(gmm_ragged), argnums=(0, 1))(x, w)
+        for g, r in zip(got, want):
+            assert float(jnp.max(jnp.abs(g - r))) < 1e-3
+
+    def test_row_tile_of_the_expert_gemms(self):
+        """deepseek-moe's expert GEMMs take n whole in one k step on 512-row
+        tiles; a decode step's few rows take one tile."""
+        assert grouped_blocks(30720, 1408, 2048) == (512, 1408, 2048)
+        assert grouped_blocks(30720, 2048, 1408) == (512, 2048, 1408)
+        assert grouped_blocks(48, 1408, 2048)[0] == 48
 
 
 class TestFlashAttention:

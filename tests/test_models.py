@@ -84,13 +84,22 @@ def test_moe_load_balance_loss_positive():
 
 
 def test_param_count_formula_matches_init():
-    for arch in ("llama3_2_1b", "qwen3_moe_30b_a3b", "zamba2_2_7b"):
-        cfg = get_smoke_config(arch)
+    import dataclasses
+    held = dataclasses.replace(get_smoke_config("deepseek_moe_16b"),
+                               experts_held=4, first_held_expert=12)
+    for cfg in [get_smoke_config(a) for a in
+                ("llama3_2_1b", "qwen3_moe_30b_a3b", "zamba2_2_7b",
+                 "deepseek_moe_16b")] + [held]:
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
         actual = sum(np.prod(p.shape) for p in jax.tree.leaves(params))
         expect = cfg.param_count()
-        assert abs(actual - expect) / actual < 0.05, (arch, actual, expect)
+        assert abs(actual - expect) / actual < 0.05, (cfg.name, actual, expect)
+    # a token touches at most top_k of the 4 experts held
+    expert = 3 * held.d_model * held.moe_d_ff
+    moe_layers = held.num_layers - held.first_dense_layers
+    assert held.param_count() - held.active_param_count() == (
+        (4 - min(held.top_k, 4)) * expert * moe_layers)
 
 
 def test_full_configs_match_assignment():
@@ -103,7 +112,12 @@ def test_full_configs_match_assignment():
     c = get_config("qwen3-moe-30b-a3b")
     assert (c.num_experts, c.top_k, c.vocab_size) == (128, 8, 151936)
     c = get_config("deepseek-moe-16b")
-    assert (c.num_experts, c.num_shared_experts, c.top_k) == (64, 2, 6)
+    assert (c.num_layers, c.first_dense_layers, c.d_model, c.num_heads,
+            c.num_kv_heads, c.head_dim, c.d_ff, c.vocab_size) == (
+        28, 1, 2048, 16, 16, 128, 10944, 102400)
+    assert (c.num_experts, c.experts_held, c.num_shared_experts, c.top_k,
+            c.moe_d_ff, c.norm_topk_prob, c.norm_eps) == (
+        64, 64, 2, 6, 1408, False, 1e-6)
     c = get_config("zamba2-2.7b")
     assert (c.num_layers, c.d_model, c.ssm_state) == (54, 2560, 64)
     c = get_config("minicpm3-4b")
