@@ -104,7 +104,7 @@ def print_run(run: ServeRun, name: str, routed: bool) -> None:
     tokens of each request, and the plan-cache table."""
     for label, w in run.warmup.items():
         print(f"[warmup] bucket {label}: {w['plans']} plans, "
-              f"{w['warm_s']:.2f}s")
+              f"{w['warm_s']:.2f}s, cache aliased {w['cache_alias']:.4f}")
     res = run.result
     q = res.latency_quantiles_ms()
     p50 = q["p50_ms"] if q["p50_ms"] is None else round(q["p50_ms"], 2)

@@ -12,7 +12,12 @@ Decode paths maintain a KV cache: full cache for GQA, rolling window cache
 for SWA (h2o-danube at 500k), and the *compressed latent* cache for MLA with
 the absorbed-matmul decode (w_uk/w_uv folded into the query/output products
 -- a schedule re-association in the spirit of the paper: same instruction
-set X, different equivariant map).
+set X, different equivariant map).  A cached call writes only its new
+slots, in place, into the cache it is given: ``at`` addresses the layer,
+``()`` for a single layer's cache (B, S, ...), ``(i,)`` for layer i of a
+stacked cache (L, B, S, ...).  A decode step (``pos`` given) attends over
+that layer where it lies; a prefill (``pos`` None: the cache holds nothing
+before the call) attends over its own keys, as training does.
 
 The qkv/output projections route through ``layers.linear``: inside a
 ``repro.plan.planned_matmuls(mesh)`` scope they dispatch through the plan
@@ -118,6 +123,17 @@ def chunked_attention(
     return o
 
 
+def _cache_write(cache: Cache, new: Cache, at: Tuple, slot) -> Cache:
+    """Write each leaf of ``new`` (B, S, ...) into the layer of ``cache``
+    that ``at`` addresses, from sequence slot ``slot``: one
+    dynamic-update-slice a leaf, which a donated cache takes in place."""
+    def put(buf, x):
+        x = x.reshape((1,) * len(at) + x.shape)
+        start = tuple(at) + (0, slot) + (0,) * (x.ndim - len(at) - 2)
+        return jax.lax.dynamic_update_slice(buf, x, start)
+    return {n: put(cache[n], new[n]) for n in new}
+
+
 # ---------------------------------------------------------------------------
 # GQA (covers MHA, MQA, SWA)
 # ---------------------------------------------------------------------------
@@ -141,9 +157,12 @@ def gqa_attention(
     pos: Optional[jax.Array] = None,
     causal: bool = True,
     offsets: Optional[jax.Array] = None,
+    at: Tuple = (),
 ) -> Tuple[jax.Array, Optional[Cache]]:
-    """x: (B, S, d).  Training/prefill when cache is None (or being filled);
-    decode when cache is provided with scalar ``pos`` (S == 1).
+    """x: (B, S, d).  With a cache, writes this call's S keys/values into
+    the layer ``at`` addresses from slot ``pos`` (0 when ``pos`` is None);
+    attends over the call's own keys when ``pos`` is None (training, a
+    prefill), else over that layer of the cache (module docstring).
 
     ``offsets`` (B,) shifts each row's logical positions for left-padded
     serving batches: cache slot j holds row i's logical position
@@ -160,20 +179,20 @@ def gqa_attention(
     k = apply_rope(k, positions, cfg.rope_theta)
 
     pdt = jnp.bfloat16 if cfg.attn_probs_dtype == "bf16" else jnp.float32
-    if cache is None:  # train / prefill without cache materialization
+    new_cache = None
+    if cache is not None:
+        s_cache = cache["k"].shape[len(at) + 1]
+        rolling = cfg.window > 0 and s_cache == cfg.window
+        slot = 0 if pos is None else (pos % s_cache) if rolling else pos
+        new_cache = _cache_write(cache, {"k": k, "v": v}, at, slot)
+    if pos is None:  # train, or a prefill: the call's own keys
         o = chunked_attention(
             q, k, v, positions, positions,
             window=cfg.window, chunk=cfg.attn_chunk, causal=causal,
             probs_dtype=pdt,
         )
-        new_cache = None
     else:
-        s_cache = cache["k"].shape[1]
-        rolling = cfg.window > 0 and s_cache == cfg.window
-        slot = (pos % s_cache) if rolling else pos
-        ck = jax.lax.dynamic_update_slice(cache["k"], k, (0, slot, 0, 0))
-        cv = jax.lax.dynamic_update_slice(cache["v"], v, (0, slot, 0, 0))
-        new_cache = {"k": ck, "v": cv}
+        ck, cv = new_cache["k"][at], new_cache["v"][at]
         idx = jnp.arange(s_cache)
         if rolling:
             # slot i holds position pos - ((pos - i) mod W); invalid (< 0)
@@ -247,7 +266,10 @@ def mla_attention(
     cache: Optional[Cache] = None,
     pos: Optional[jax.Array] = None,
     offsets: Optional[jax.Array] = None,
+    at: Tuple = (),
 ) -> Tuple[jax.Array, Optional[Cache]]:
+    """As ``gqa_attention``; a cache holds the latent ``c_kv`` and the
+    shared ``k_rope``, attended in the latent space (absorbed decode)."""
     b, s, d = x.shape
     h = cfg.num_heads
     nope, rope, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
@@ -256,7 +278,11 @@ def mla_attention(
     q_nope, q_rope = _mla_q(p, x, cfg, positions)
     c_kv, k_rope = _mla_latent(p, x, cfg, positions)
 
-    if cache is None:
+    new_cache = None
+    if cache is not None:
+        new_cache = _cache_write(cache, {"c_kv": c_kv, "k_rope": k_rope},
+                                 at, 0 if pos is None else pos)
+    if pos is None:
         # expanded path: materialize per-head K/V from the latent
         kvb = linear(c_kv, p["wkv_b"]).reshape(b, s, h, nope + vd)
         k_nope, v = kvb[..., :nope], kvb[..., nope:]
@@ -270,12 +296,9 @@ def mla_attention(
             probs_dtype=jnp.bfloat16 if cfg.attn_probs_dtype == "bf16"
             else jnp.float32,
         )
-        new_cache = None
     else:
         # absorbed decode: attend in the kv_lora_rank latent space
-        cc = jax.lax.dynamic_update_slice(cache["c_kv"], c_kv, (0, pos, 0))
-        cr = jax.lax.dynamic_update_slice(cache["k_rope"], k_rope, (0, pos, 0))
-        new_cache = {"c_kv": cc, "k_rope": cr}
+        cc, cr = new_cache["c_kv"][at], new_cache["k_rope"][at]
         # wkv_b columns are per-head blocks of (nope + vd) -- must match the
         # expanded path's reshape(b, s, h, nope + vd) exactly
         w_b = p["wkv_b"].reshape(kvr, h, nope + vd)

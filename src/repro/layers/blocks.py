@@ -64,17 +64,20 @@ def block_apply(
     cache: Optional[Dict] = None,
     pos: Optional[jax.Array] = None,
     offsets: Optional[jax.Array] = None,
+    at: Tuple = (),
 ) -> Tuple[jax.Array, jax.Array, Optional[Dict]]:
     """``offsets`` (B,) carries per-row left-padding amounts down to the
     attention layers (logical-position masking for padded serving batches);
-    the recurrent kinds have no position concept and ignore it."""
+    the recurrent kinds have no position concept and ignore it.  ``at``
+    addresses this block's layer in an attention cache (``()`` a single
+    layer's cache, ``(i,)`` layer i of a stack; ``layers.attention``)."""
     aux = jnp.zeros((), jnp.float32)
     x = constrain(x, "batch", None, None)
     if kind in ("attn_mlp", "attn_moe"):
         attn_fn = mla_attention if cfg.attn_type == "mla" else gqa_attention
         h = rms_norm(x, p["attn_norm"], cfg.norm_eps)
         a, new_cache = attn_fn(p["attn"], h, cfg, positions, cache, pos,
-                               offsets=offsets)
+                               offsets=offsets, at=at)
         x = x + constrain(a, "batch", None, None)
         h = rms_norm(x, p["mlp_norm"], cfg.norm_eps)
         if kind == "attn_mlp":

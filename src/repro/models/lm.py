@@ -3,8 +3,9 @@
 Layers are *stacked* (one leading L axis per parameter) and executed with
 ``jax.lax.scan`` so the HLO -- and hence the 512-device dry-run compile time
 -- is depth-independent.  deepseek-moe's leading dense layers live in their
-own (short) stack.  Decode threads the per-layer KV caches through the same
-scan.  Remat policy is configurable per config (none | dots | full).
+own (short) stack.  Decode carries each stacked KV cache through the same
+scan and updates it in place.  Remat policy is configurable per config
+(none | dots | full).
 """
 from __future__ import annotations
 
@@ -13,6 +14,7 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import with_layout_constraint
 
 from repro.layers.blocks import block_apply, block_params
 from repro.layers.attention import gqa_cache, mla_cache
@@ -128,9 +130,9 @@ class DecoderLM:
         self, params: Params, cache: Cache, tokens: jax.Array,
         offsets: Optional[jax.Array] = None,
     ) -> Tuple[jax.Array, Cache]:
-        """One-pass prompt ingestion: runs the full (B, S_prompt) forward
-        through the *cached* attention path (writes K/V at positions
-        [0, S)), returning last-token logits + the filled cache.  The
+        """One-pass prompt ingestion: runs the full (B, S_prompt) forward,
+        attending over the prompt's own keys, and writes K/V at slots
+        [0, S) of ``cache``; returns last-token logits + the cache.  The
         production serving path: prompt cost is one forward instead of
         S_prompt decode steps.
 
@@ -141,51 +143,56 @@ class DecoderLM:
         positions = jnp.arange(tokens.shape[1])
         if offsets is not None:
             positions = positions[None, :] - offsets[:, None]
-        return self._cached_forward(params, cache, tokens,
-                                    positions, jnp.int32(0), offsets)
+        return self._cached_forward(params, cache, tokens, positions, None,
+                                    offsets)
 
     def decode_step(
         self, params: Params, cache: Cache, tokens: jax.Array, pos: jax.Array,
-        offsets: Optional[jax.Array] = None,
+        offsets: Optional[jax.Array] = None, layouts: Optional[Cache] = None,
     ) -> Tuple[jax.Array, Cache]:
         """tokens: (B, 1); pos: scalar int32 (the absolute cache slot).
         Returns (logits (B, V), cache).  ``offsets`` as in ``prefill``:
-        row i's logical query position is pos - offsets[i]."""
+        row i's logical query position is pos - offsets[i].  ``layouts``
+        (a tree like ``cache`` of ``jax.experimental.layout.Layout``) holds
+        each stacked cache to its device layout through the layer loop;
+        without it XLA may relayout the whole stack on the loop's entry and
+        exit, to suit the attention."""
         if offsets is not None:
             positions = pos - offsets[:, None]  # (B, 1) logical positions
         else:
             positions = jnp.full((1,), pos, jnp.int32)
         return self._cached_forward(params, cache, tokens, positions, pos,
-                                    offsets)
+                                    offsets, layouts)
 
     def _cached_forward(
         self, params: Params, cache: Cache, tokens: jax.Array,
-        positions: jax.Array, pos: jax.Array,
-        offsets: Optional[jax.Array] = None,
+        positions: jax.Array, pos: Optional[jax.Array],
+        offsets: Optional[jax.Array] = None, layouts: Optional[Cache] = None,
     ) -> Tuple[jax.Array, Cache]:
+        """The layer scan carries each stacked cache: layer i writes its new
+        slots into the stack at i and attends over the stack's layer i, so
+        a donated cache is updated in place, with no per-layer copy in or
+        out of the scan."""
         cfg = self.cfg
         x = embed(params["embed"], tokens)
         new_cache: Cache = {}
+        stacks = (("dense_layers", "attn_mlp"), ("layers", self.kind))
+        for name, kind in stacks:
+            if name not in cache:
+                continue
 
-        if cfg.first_dense_layers:
-            def dense_body(x, lp_lc):
-                lp, lc = lp_lc
-                x, _, nc = block_apply(lp, x, cfg, "attn_mlp", positions, lc,
-                                       pos, offsets)
-                return x, nc
-            x, new_cache["dense_layers"] = jax.lax.scan(
-                dense_body, x, (params["dense_layers"], cache["dense_layers"])
-            )
+            def body(carry, lp_i, kind=kind):
+                x, stack = carry
+                lp, i = lp_i
+                if layouts is not None:
+                    stack = with_layout_constraint(stack, layouts[name])
+                x, _, stack = block_apply(lp, x, cfg, kind, positions, stack,
+                                          pos, offsets, at=(i,))
+                return (x, stack), None
 
-        def body(x, lp_lc):
-            lp, lc = lp_lc
-            x, _, nc = block_apply(lp, x, cfg, self.kind, positions, lc, pos,
-                                   offsets)
-            return x, nc
-
-        x, new_cache["layers"] = jax.lax.scan(
-            body, x, (params["layers"], cache["layers"])
-        )
+            n = jax.tree.leaves(cache[name])[0].shape[0]
+            (x, new_cache[name]), _ = jax.lax.scan(
+                body, (x, cache[name]), (params[name], jnp.arange(n)))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         logits = unembed(params["embed"], x, cfg.vocab_size)[:, -1]
         return logits, new_cache

@@ -28,11 +28,12 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
-from typing import Optional, Sequence, Tuple
+from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro import obs
 
@@ -140,32 +141,42 @@ def planned_scope(mesh, strategy: Optional[str] = None, tuning=None):
     return contextlib.nullcontext()
 
 
-@functools.lru_cache(maxsize=None)
-def _default_prefill(model, mesh=None, strategy: Optional[str] = None,
-                     tuning=None):
-    """Memoized per (model, mesh, strategy) prefill: one-pass for models
-    with ``prefill`` (DecoderLM), teacher-forced step loop otherwise
-    (recurrent families).
+def _prefill_fn(model, mesh, strategy, tuning):
+    """The prefill as the jitted function ``prefill``.
 
-    The plan scope is (re-)entered INSIDE the jitted closure, not just
+    The plan scope is (re-)entered INSIDE the traced function, not just
     around the call: JAX's trace cache is keyed on the traced callable,
     and equal bound methods (``model.prefill``) would share a jaxpr traced
     earlier WITHOUT the scope -- silently skipping plan routing.  A
     closure per (model, mesh, strategy, tuning) gets its own trace-cache
-    entry and
-    reads the contextvar while tracing; the memo makes repeated
-    ``generate`` calls with the same config reuse it instead of retracing.
-    """
-    if hasattr(model, "prefill"):
-        def prefill(params, cache, tokens, offsets=None):
-            with planned_scope(mesh, strategy, tuning):
-                if offsets is not None:
-                    return model.prefill(params, cache, tokens, offsets)
-                return model.prefill(params, cache, tokens)
+    entry and reads the contextvar while tracing."""
+    def prefill(params, cache, tokens, offsets=None):
+        with planned_scope(mesh, strategy, tuning):
+            if offsets is not None:
+                return model.prefill(params, cache, tokens, offsets)
+            return model.prefill(params, cache, tokens)
 
-        return jax.jit(prefill)
-    step = _default_step(model, mesh, strategy, tuning)
+    return prefill
 
+
+def _step_fn(model, mesh, strategy, tuning, layouts=None):
+    """The decode step as the jitted function ``step`` (scope as in
+    ``_prefill_fn``); ``layouts`` as in ``DecoderLM.decode_step``."""
+    kw = {} if layouts is None else {"layouts": layouts}
+
+    def step(params, cache, tokens, pos, offsets=None):
+        with planned_scope(mesh, strategy, tuning):
+            if offsets is not None:
+                return model.decode_step(params, cache, tokens, pos, offsets,
+                                         **kw)
+            return model.decode_step(params, cache, tokens, pos, **kw)
+
+    return step
+
+
+def _step_loop(step):
+    """Teacher-forced prefill for models without ``prefill`` (the
+    recurrent families): one ``step`` per prompt token."""
     def loop(params, cache, tokens):
         logits = None
         for t in range(tokens.shape[1]):
@@ -177,15 +188,81 @@ def _default_prefill(model, mesh=None, strategy: Optional[str] = None,
 
 
 @functools.lru_cache(maxsize=None)
+def _default_prefill(model, mesh=None, strategy: Optional[str] = None,
+                     tuning=None):
+    """Memoized per (model, mesh, strategy, tuning) prefill: one-pass for
+    models with ``prefill`` (DecoderLM), the step loop otherwise.  Like
+    ``_default_step`` it takes the cache donated."""
+    if hasattr(model, "prefill"):
+        return jax.jit(_prefill_fn(model, mesh, strategy, tuning),
+                       donate_argnums=(1,))
+    return _step_loop(_default_step(model, mesh, strategy, tuning))
+
+
+@functools.lru_cache(maxsize=None)
 def _default_step(model, mesh=None, strategy: Optional[str] = None,
                   tuning=None):
-    def step(params, cache, tokens, pos, offsets=None):
-        with planned_scope(mesh, strategy, tuning):
-            if offsets is not None:
-                return model.decode_step(params, cache, tokens, pos, offsets)
-            return model.decode_step(params, cache, tokens, pos)
+    """Memoized per (model, mesh, strategy, tuning) decode step.
 
-    return jax.jit(step)
+    The cache argument is donated: the model updates it in place, and the
+    buffers passed in are deleted by the call.  A caller rebinds its cache
+    to the one returned and never reads the one it passed."""
+    return jax.jit(_step_fn(model, mesh, strategy, tuning),
+                   donate_argnums=(1,))
+
+
+@dataclasses.dataclass(frozen=True)
+class BucketPrograms:
+    """One (batch, seq) bucket's compiled programs.  ``init_cache()`` makes
+    a cache; the prefill and each decode step take it donated and return
+    it updated in place, all in the default device format."""
+
+    prefill: Callable
+    step: Callable
+    init_cache: Callable
+
+
+def compile_bucket(model, params, batch: int, seq: int, max_seq: int, *,
+                   offsets: bool, mesh=None, strategy: Optional[str] = None,
+                   tuning=None) -> BucketPrograms:
+    """AOT-compile a bucket's programs.  ``params`` may be arrays or
+    ``jax.ShapeDtypeStruct``s; the cache, tokens and logits live where the
+    parameters do, or replicated over ``mesh`` when it routes the matmuls.
+
+    Every program keeps the cache in the default format: a program read
+    back from JAX's persistent compilation cache returns its outputs in
+    the default layout (jax 0.9, TPU and CPU), whatever format it was
+    compiled for.  The prefill only writes the cache, which no format
+    makes it copy; the step holds each stacked cache to that format
+    through its layer loop, where XLA would otherwise relayout the whole
+    stack around the loop to suit the attention."""
+    if mesh is not None and getattr(mesh, "size", 1) > 1:
+        home = NamedSharding(mesh, PartitionSpec())
+    else:
+        home = jax.tree.leaves(params)[0].sharding
+    cache = jax.eval_shape(lambda: model.init_cache(batch, max_seq))
+    rows = (jax.ShapeDtypeStruct((batch,), jnp.int32),) if offsets else ()
+
+    def compiled(fn, *args):
+        shardings = (home,) * (2 + len(args) + len(rows))
+        return jax.jit(fn, donate_argnums=(1,), in_shardings=shardings,
+                       out_shardings=(home, home)).lower(
+                           params, cache, *args, *rows).compile()
+
+    init = jax.jit(lambda: model.init_cache(batch, max_seq),
+                   out_shardings=home).lower().compile()
+    one_pass = hasattr(model, "prefill")
+    layouts = (jax.tree.map(lambda f: f.layout, init.output_formats)
+               if one_pass else None)
+    step = compiled(_step_fn(model, mesh, strategy, tuning, layouts),
+                    jax.ShapeDtypeStruct((batch, 1), jnp.int32),
+                    jax.ShapeDtypeStruct((), jnp.int32))
+    if one_pass:
+        prefill = compiled(_prefill_fn(model, mesh, strategy, tuning),
+                           jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+    else:
+        prefill = _step_loop(step)
+    return BucketPrograms(prefill, step, init)
 
 
 def _sample(logits: jax.Array, cfg: ServeConfig, key) -> jax.Array:

@@ -3,25 +3,31 @@ AOT plan-cache warmup, latency accounting.
 
 The seed-era ``runtime.serve.generate`` builds a fresh ``jax.jit`` wrapper
 per call, so every request pays trace + compile + planning.  ``Server``
-holds ONE jitted prefill and ONE jitted decode step for the lifetime of
-the process and AOT-warms them over a declared (batch, seq) bucket grid:
+holds one compiled prefill and one compiled decode step per (batch, seq)
+shape for the lifetime of the process, and warms those of a declared
+bucket grid:
 
-  * ``warmup()`` runs a dummy prefill + decode step per bucket inside the
-    ``planned_matmuls(mesh)`` scope.  Tracing routes every layer matmul
-    through ``repro.plan.build_plan``, so the plan cache fills with each
-    bucket's ``SchedulePlan``s and XLA compiles the bucket's program pair.
-    The plans inserted per bucket are snapshotted (key -> plan).
+  * ``warmup()`` AOT-compiles each bucket's prefill + decode step
+    (``runtime.serve.compile_bucket``: the cache donated and updated in
+    place) and runs them once on a dummy batch.
+    Tracing routes every layer matmul through ``repro.plan.build_plan``
+    inside the ``planned_matmuls(mesh)`` scope, so the plan cache fills
+    with each bucket's ``SchedulePlan``s.  The plans inserted per bucket
+    are snapshotted (key -> plan).
   * ``generate()`` routes the request batch to the nearest warm bucket
     (left-padding prompts to ``bucket.seq`` with per-row position offsets,
     padding the batch with dummy rows to ``bucket.batch``), re-``get``s the
     bucket's plan keys from the cache -- all hits after warmup; an evicted
-    plan is re-pinned from the snapshot -- and decodes with the warm
-    compiled functions.  Per-token wall latencies and TTFT are measured
+    plan is re-pinned from the snapshot -- and decodes with the bucket's
+    compiled pair (a batch that fits no bucket is served at its own shape,
+    compiled on first use).  Per-token wall latencies and TTFT are measured
     around the blocking device calls.
 
 Observability: ``obs`` spans at every phase, which reach the profiler's
 trace whenever a ``jax.profiler`` session records.  ``serve.warmup``
-(``bucket``, ``plans``) per warmed bucket; per ``generate`` call,
+(``bucket``, ``plans``) per warmed bucket, and the counter
+``serve.cache_alias`` (label ``bucket``): the compiled step's aliased
+bytes over its cache's bytes; per ``generate`` call,
 ``serve.generate`` (``requests``, ``bucket``: a label or ``"cold"``)
 around ``serve.prepare`` (routing, the plan probe, batching, the token
 upload, the cache; ``repinned``: plans re-pinned by the probe),
@@ -44,8 +50,9 @@ import numpy as np
 
 from repro import obs
 from repro.plan.cache import plan_cache
-from repro.runtime.serve import (ServeConfig, _default_prefill, _default_step,
-                                 _sample, batch_requests, planned_scope)
+from repro.runtime.serve import (BucketPrograms, ServeConfig, _sample,
+                                 batch_requests, compile_bucket,
+                                 planned_scope)
 
 from .buckets import Bucket, as_bucket, route
 
@@ -113,11 +120,14 @@ class Server:
             cfg.validate_prompt_len(b.seq)
         self._uses_offsets = bool(
             getattr(model, "supports_position_offsets", False))
-        # ONE persistent compiled function pair for the server's lifetime;
-        # the plan scope lives INSIDE the jitted closure so this server's
-        # trace-cache entries are its own (see runtime.serve._default_*)
-        self._prefill = _default_prefill(model, mesh, strategy, tuning)
-        self._step = _default_step(model, mesh, strategy, tuning)
+        # per served (batch, seq) shape, warmed bucket or cold: its
+        # compiled programs, for the server's lifetime; the plan scope
+        # lives INSIDE the traced functions, so this server's programs
+        # are its own (see runtime.serve._prefill_fn)
+        self._programs: Dict[Bucket, BucketPrograms] = {}
+        # per warmed bucket: the share of its cache the compiled step
+        # aliases
+        self._cache_alias: Dict[Bucket, float] = {}
         # per-bucket plan snapshot: key -> SchedulePlan inserted by warmup
         self._bucket_plans: Dict[Bucket, Dict] = {}
         # per-bucket tuning keys the warmup searches populated (a live
@@ -131,9 +141,10 @@ class Server:
     def warmup(self, buckets: Optional[Sequence] = None) -> Dict:
         """AOT-warm every bucket: compile the (prefill, step) program pair
         and populate the plan cache with the bucket's ``SchedulePlan``s.
-        Returns per-bucket accounting ``{label: {plans, warm_s}}``; after
-        this, requests routed to a warm bucket pay zero planning/compile
-        cost and ``cache_report()`` pins the serve-window hit rate."""
+        Returns per-bucket accounting ``{label: {plans, warm_s,
+        cache_alias}}``; after this, requests routed to a warm bucket pay
+        zero planning/compile cost and ``cache_report()`` pins the
+        serve-window hit rate."""
         buckets = self.buckets if buckets is None else tuple(
             as_bucket(b) for b in buckets)
         report: Dict[str, Dict] = {}
@@ -145,6 +156,7 @@ class Server:
             report[bucket.label] = {
                 "plans": n_plans,
                 "warm_s": time.perf_counter() - t0,
+                "cache_alias": self._cache_alias[bucket],
             }
         self._warm_cache_info = plan_cache.info()
         if self.tuning is not None and hasattr(self.tuning, "stats"):
@@ -158,25 +170,26 @@ class Server:
         tune_before = (set(self.tuning.keys())
                        if self.tuning is not None
                        and hasattr(self.tuning, "keys") else set())
+        progs = self._programs_for(bucket)
         toks = jnp.full((bucket.batch, bucket.seq), self.dummy_token,
                         jnp.int32)
-        cache = self.model.init_cache(bucket.batch, self.cfg.max_seq)
         offsets = (jnp.zeros((bucket.batch,), jnp.int32)
                    if self._uses_offsets else None)
-        key = jax.random.PRNGKey(0)
-        with planned_scope(self.mesh, self.strategy, self.tuning):
-            logits, cache = self._call_prefill(cache, toks, offsets)
-            # two steps, not one: step 2's inputs carry the shardings
-            # step 1's outputs committed them to, a different jit
-            # signature than the fresh init_cache warmup step -- one
-            # step would leave serving to compile that steady state
-            # mid-decode
-            for i in range(min(2, self.cfg.max_new_tokens)):
-                cur = _sample(logits, self.cfg, key)
-                logits, cache = self._call_step(
-                    cache, cur[:, None], jnp.int32(bucket.seq + i),
-                    offsets)
-            jax.block_until_ready(logits)
+        logits, cache = self._call(progs.prefill, progs.init_cache(), toks,
+                                   offsets)
+        # the compiled step's aliased bytes over the device bytes of the
+        # cache it takes: 1.0 when it updates the whole cache in place
+        alias = (progs.step.memory_analysis().alias_size_in_bytes
+                 / sum(a.on_device_size_in_bytes()
+                       for a in jax.tree.leaves(cache)))
+        self._cache_alias[bucket] = alias
+        if obs.enabled():
+            obs.counter("serve.cache_alias").inc(alias, bucket=bucket.label)
+        if self.cfg.max_new_tokens > 0:
+            cur = _sample(logits, self.cfg, jax.random.PRNGKey(0))
+            logits, cache = self._call(progs.step, cache, cur[:, None],
+                                       jnp.int32(bucket.seq), offsets)
+        jax.block_until_ready(logits)
         new_keys = [k for k in plan_cache.keys() if k not in before]
         snapshot = {k: plan_cache.get(k) for k in new_keys}
         # a later bucket can share plans with an earlier one (same decode
@@ -232,15 +245,16 @@ class Server:
                 sp = tokens.shape[1]
                 offsets = (jnp.asarray(sp - lens, jnp.int32)
                            if self._uses_offsets else None)
-                cache = self.model.init_cache(b_rows, self.cfg.max_seq)
+                progs = self._programs_for(Bucket(b_rows, sp))
+                cache = progs.init_cache()
 
             out = [tokens]
             step_lat: List[float] = []
             with planned_scope(self.mesh, self.strategy, self.tuning):
                 with obs.span("serve.prefill", batch=b_rows, seq=sp,
                               tokens=sum(len(p) for p in prompt_list)):
-                    logits, cache = self._call_prefill(cache, tokens,
-                                                       offsets)
+                    logits, cache = self._call(progs.prefill, cache,
+                                               tokens, offsets)
                 prefill_logits = logits
                 if self.cfg.max_new_tokens > 0:
                     with obs.span("serve.first_token"):
@@ -255,9 +269,9 @@ class Server:
                             t0 = time.perf_counter()
                             with obs.span("serve.decode_step", batch=b_rows,
                                           pos=t):
-                                logits, cache = self._call_step(
-                                    cache, cur[:, None], jnp.int32(t),
-                                    offsets)
+                                logits, cache = self._call(
+                                    progs.step, cache, cur[:, None],
+                                    jnp.int32(t), offsets)
                                 cur = _sample(logits, self.cfg, sub)
                                 jax.block_until_ready(cur)
                             step_lat.append(time.perf_counter() - t0)
@@ -337,15 +351,23 @@ class Server:
 
     # -- internals -----------------------------------------------------------
 
-    def _call_prefill(self, cache, tokens, offsets):
-        if offsets is not None:
-            return self._prefill(self.params, cache, tokens, offsets)
-        return self._prefill(self.params, cache, tokens)
+    def _programs_for(self, shape: Bucket) -> BucketPrograms:
+        """The compiled programs of a (batch, seq) shape, compiled on first
+        use: at warmup for a bucket, in ``generate`` for a cold shape."""
+        if shape not in self._programs:
+            self._programs[shape] = compile_bucket(
+                self.model, self.params, shape.batch, shape.seq,
+                self.cfg.max_seq, offsets=self._uses_offsets, mesh=self.mesh,
+                strategy=self.strategy, tuning=self.tuning)
+        return self._programs[shape]
 
-    def _call_step(self, cache, cur, pos, offsets):
-        if offsets is not None:
-            return self._step(self.params, cache, cur, pos, offsets)
-        return self._step(self.params, cache, cur, pos)
+    def _call(self, fn, cache, *args):
+        """``fn(params, cache, *args)``, leaving out ``offsets`` (the last
+        argument) when it is None.  The cache is donated: rebind it to the
+        one returned."""
+        if args[-1] is None:
+            args = args[:-1]
+        return fn(self.params, cache, *args)
 
 def warmup(model, params, cfg: ServeConfig, *, mesh=None,
            buckets: Sequence = DEFAULT_BUCKETS,

@@ -9,7 +9,9 @@ The topology is described only inside the module fixture: one process at a
 time may load the TPU library, so no import, ``parametrize`` or ``skipif``
 may touch it.  Keep every such compile in this one file.
 """
+import dataclasses
 import functools
+import math
 import os
 import re
 
@@ -18,11 +20,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.kernels.matmul import matmul
 from repro.kernels.matmul.grouped import gmm_pallas
 from repro.kernels.matmul.kernel import vmem_working_set_bytes, zorder_matmul
 from repro.mesh import make_mesh
+from repro.models.registry import build_model
 from repro.plan import build_plan, lower_shard_map
+from repro.runtime.serve import compile_bucket
 from repro.tune import candidate_space
 
 
@@ -121,3 +126,112 @@ def test_grouped_kernel_compiles(one_chip, m, k, n):
     sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
     hlo = _hlo(gmm_pallas, x, w, sizes)
     assert "tpu_custom_call" in hlo and "grouped_matmul" in hlo
+
+
+# -- the serving pair: the KV cache updated in place -------------------------
+
+_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9-]*)\((.*)$")
+_ARRAY = re.compile(r"([a-z]+\d*)\[([\d,]*)\]\{([^}]*)\}")
+# ops that write no new buffer: the async starts' results hold their operand
+_NO_WRITE = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+             "conditional", "call", "constant", "copy-start", "slice-start"}
+
+
+def _computations(hlo):
+    """The entry's name, and {computation: {op: (type, opcode, rest)}}."""
+    comps, cur, entry = {}, None, None
+    for line in hlo.splitlines():
+        if line and not line.startswith(" "):
+            m = re.match(r"(ENTRY )?%(\S+) .*\{$", line)
+            cur = m and m.group(2)
+            if m:
+                comps[cur] = {}
+                entry = cur if m.group(1) else entry
+        elif cur and (m := _OP.match(line)):
+            comps[cur][m.group(1)] = m.groups()[1:]
+    return entry, comps
+
+
+def _size(dims):
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+def _cache_writes(hlo, layer, max_seq):
+    """(whether in the entry computation, op name) of each op outside a
+    fusion's body that writes a bf16 buffer to HBM (no memory space in its
+    layout), of the cache's sequence length and a multiple of a layer,
+    other than a dynamic-update-slice, or a fusion whose first one, that
+    writes less than a layer into the cache: the token's or the prompt's
+    slots."""
+    entry, comps = _computations(hlo)
+    fused = {c for ops in comps.values() for _, opcode, rest in ops.values()
+             if opcode == "fusion"
+             for c in re.findall(r"calls=%([\w.\-]+)", rest)}
+
+    def small(comp, rest):
+        update = re.findall(r"%([\w.\-]+)", rest)[1]
+        return all(_size(d) < layer for _, d, _ in
+                   _ARRAY.findall(comps[comp][update][0]))
+
+    def small_update(comp):
+        for typ, opcode, rest in comps[comp].values():
+            if opcode == "dynamic-update-slice":
+                return small(comp, rest)
+            if opcode == "fusion":
+                inner = small_update(re.search(r"calls=%([\w.\-]+)", rest)[1])
+                if inner is not None:
+                    return inner
+        return None
+
+    out = []
+    for comp, ops in comps.items():
+        if comp in fused:
+            continue
+        for name, (typ, opcode, rest) in ops.items():
+            if opcode in _NO_WRITE or not any(
+                    dt == "bf16" and _size(d) % layer == 0
+                    and str(max_seq) in d.split(",") and "S(" not in lay
+                    for dt, d, lay in _ARRAY.findall(typ)):
+                continue
+            call = re.search(r"calls=%([\w.\-]+)", rest)
+            if opcode == "fusion" and small_update(call[1]):
+                continue
+            if opcode == "dynamic-update-slice" and small(comp, rest):
+                continue
+            out.append((comp == entry, name))
+    return out
+
+
+# h2o-danube-3-4b as the serve cell runs it (no window), and deepseek-moe-16b
+# at one chip's share of its experts; three layers for dsmoe, so that behind
+# its leading dense layer the MoE stack is a loop as at full depth (a stack
+# of one layer is not looped, and XLA then stages its cache through VMEM)
+@pytest.mark.parametrize("config,changes", [
+    ("h2o-danube-3-4b", dict(num_layers=2, window=0, tie_embeddings=False)),
+    ("deepseek-moe-16b", dict(num_layers=3, experts_held=8)),
+])
+def test_serving_pair_updates_cache_in_place(one_chip, config, changes):
+    batch, seq, max_seq = 8, 512, 2048
+    cfg = dataclasses.replace(get_config(config), dtype="bfloat16", **changes)
+    model = build_model(cfg)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    progs = compile_bucket(model, params, batch, seq, max_seq, offsets=True)
+    cache = jax.eval_shape(lambda: model.init_cache(batch, max_seq))
+    leaves = jax.tree.leaves(cache)
+    layer = batch * max_seq * cfg.num_kv_heads * cfg.head_dim
+    for c in (progs.step, progs.prefill):
+        # the donated cache is the output's buffer, all of it
+        assert c.memory_analysis().alias_size_in_bytes >= sum(
+            a.size * a.dtype.itemsize for a in leaves)
+        assert c.input_formats[0][1] == c.output_formats[1]
+    # one format, the default, from the cache's making to its last step
+    assert progs.init_cache.output_formats == progs.prefill.input_formats[0][1]
+    assert progs.prefill.input_formats[0][1] == progs.step.input_formats[0][1]
+    # neither program writes a layer of the cache anywhere: only the
+    # token's slot, or the prompt's; a stack of one layer (dsmoe's dense
+    # one) is staged through VMEM and written back once
+    for c in (progs.step, progs.prefill):
+        writes = _cache_writes(c.as_text(), layer, max_seq)
+        assert len(writes) <= ("dense_layers" in cache), writes

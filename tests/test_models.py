@@ -178,10 +178,12 @@ class TestRecurrentCores:
         assert float(jnp.max(jnp.abs(y - ref))) < 1e-4
 
 
-@pytest.mark.parametrize("arch", ["llama3_2_1b", "minicpm3_4b"])
+@pytest.mark.parametrize("arch", ["llama3_2_1b", "minicpm3_4b",
+                                  "h2o_danube3_4b"])
 def test_prefill_matches_stepwise_decode(arch):
     """One-pass prefill must fill the cache identically to step-by-step
-    decode (and return the same last-token logits)."""
+    decode (and return the same last-token logits); h2o-danube's smoke
+    config fills a rolling window of 16 with 12 prompt tokens."""
     cfg = get_smoke_config(arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -210,3 +212,100 @@ def test_prefill_matches_stepwise_decode(arch):
     la, _ = step(params, cache_a, nxt, jnp.int32(T))
     lb, _ = step(params, cache_b, nxt, jnp.int32(T))
     assert float(jnp.max(jnp.abs(la - lb))) / scale < 0.05
+
+
+def _xs_ys_forward(model, params, cache, tokens, positions, pos,
+                   offsets=None, layouts=None):
+    """The cached layer scan as it was before the cache was carried: each
+    layer's cache goes in as the scan's ``xs`` and comes back, rewritten
+    whole, as its ``ys``."""
+    from repro.layers.blocks import block_apply
+    from repro.layers.embed import embed, unembed
+    from repro.layers.norms import rms_norm
+
+    cfg = model.cfg
+    x = embed(params["embed"], tokens)
+    new_cache = {}
+    stacks = (("dense_layers", "attn_mlp"), ("layers", model.kind))
+    for name, kind in stacks:
+        if name not in cache:
+            continue
+
+        def body(x, lp_lc, kind=kind):
+            lp, lc = lp_lc
+            x, _, nc = block_apply(lp, x, cfg, kind, positions, lc, pos,
+                                   offsets)
+            return x, nc
+
+        x, new_cache[name] = jax.lax.scan(body, x,
+                                          (params[name], cache[name]))
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return unembed(params["embed"], x, cfg.vocab_size)[:, -1], new_cache
+
+
+# GQA full cache, rolling window (16 slots, crossed below), MQA, MLA, and
+# MHA with a leading dense layer
+@pytest.mark.parametrize("with_offsets", [False, True])
+@pytest.mark.parametrize("arch", ["llama3_2_1b", "h2o_danube3_4b",
+                                  "granite_20b", "minicpm3_4b",
+                                  "deepseek_moe_16b"])
+def test_carried_cache_matches_xs_ys_scan(arch, with_offsets, monkeypatch):
+    """The carried, in-place cache gives bitwise the logits and cache
+    contents of the old xs/ys layer scan, through a prefill and decode
+    steps past the rolling window."""
+    cfg = get_smoke_config(arch)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    prompt, steps, max_seq = 8, 12, 32
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (B, prompt + steps),
+                                1, cfg.vocab_size)
+    offsets = jnp.asarray([0, 3], jnp.int32) if with_offsets else None
+
+    def run():
+        extra = (offsets,) if with_offsets else ()
+        cache = model.init_cache(B, max_seq)
+        logits, cache = jax.jit(model.prefill)(params, cache,
+                                               tokens[:, :prompt], *extra)
+        out = [logits]
+        step = jax.jit(model.decode_step)
+        for t in range(prompt, prompt + steps):
+            logits, cache = step(params, cache, tokens[:, t:t + 1],
+                                 jnp.int32(t), *extra)
+            out.append(logits)
+        return out, cache
+
+    new_logits, new_cache = run()
+    monkeypatch.setattr(model, "_cached_forward",
+                        lambda *a: _xs_ys_forward(model, *a))
+    old_logits, old_cache = run()
+    for t, (a, b) in enumerate(zip(new_logits, old_logits)):
+        assert np.array_equal(np.asarray(a), np.asarray(b)), t
+    assert jax.tree.structure(new_cache) == jax.tree.structure(old_cache)
+    for a, b in zip(jax.tree.leaves(new_cache), jax.tree.leaves(old_cache)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("arch", ["llama3_2_1b", "minicpm3_4b",
+                                  "deepseek_moe_16b"])
+def test_prefill_reads_nothing_of_the_cache(arch):
+    """A prefill attends over the prompt's own keys and only writes the
+    cache: given one full of NaN it returns bitwise the logits, and the
+    prompt's slots, of a fresh one (a read of a masked slot would carry
+    the NaN through its zero weight)."""
+    cfg = get_smoke_config(arch)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    prompt, max_seq = 8, 32
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (B, prompt), 1,
+                                cfg.vocab_size)
+    offsets = jnp.asarray([0, 3], jnp.int32)
+    fresh = model.init_cache(B, max_seq)
+    stale = jax.tree.map(lambda a: jnp.full(a.shape, jnp.nan, a.dtype),
+                         fresh)
+    prefill = jax.jit(model.prefill)
+    la, ca = prefill(params, fresh, tokens, offsets)
+    lb, cb = prefill(params, stale, tokens, offsets)
+    assert np.array_equal(np.asarray(la), np.asarray(lb))
+    for a, b in zip(jax.tree.leaves(ca), jax.tree.leaves(cb)):
+        assert np.array_equal(np.asarray(a[:, :, :prompt]),
+                              np.asarray(b[:, :, :prompt]))

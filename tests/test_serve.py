@@ -16,12 +16,15 @@ import sys
 from collections import Counter
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.configs import get_smoke_config
 from repro.models.registry import build_model
-from repro.runtime.serve import ServeConfig, batch_requests, generate
+from repro.runtime.serve import (ServeConfig, _default_prefill, _default_step,
+                                 batch_requests, generate)
 from repro.serve import Bucket, Server, bucket_grid, route, warmup
 
 
@@ -221,6 +224,27 @@ def test_server_empty_cold_and_null_latency(llama):
     assert r0.latency_quantiles_ms() == {"p50_ms": None, "p99_ms": None}
 
 
+def test_server_compiles_a_cold_shape_once(llama):
+    """A batch that fits no bucket is served at its own shape, through the
+    same compiled programs as a bucket's, compiled on first use and kept:
+    a second such batch compiles nothing and decodes as the plain path."""
+    _, model, params = llama
+    scfg = ServeConfig(max_new_tokens=3, max_seq=64)
+    srv = Server(model, params, scfg, buckets=[(2, 8)])
+    srv.warmup()
+    prompts = [[4, 5, 6] * 4, [7, 8] * 5, [9] * 3]  # 3 rows, 12 slots: cold
+    first = srv.generate(prompts)
+    shapes = dict(srv._programs)
+    second = srv.generate(prompts)
+    assert first.bucket is None and second.bucket is None
+    assert srv._programs == shapes and len(shapes) == 2
+    assert second.new_tokens == first.new_tokens
+    batch, lens = batch_requests(prompts)
+    plain = generate(model, params, batch, scfg, lens=lens)
+    for i, p in enumerate(prompts):
+        assert first.new_tokens[i] == plain[i, batch.shape[1]:].tolist()
+
+
 def test_server_generate_spans_on_profiler_clock(llama, tmp_path,
                                                  xplane_host_events):
     """Every phase of ``generate`` is a host span in the profiler's trace,
@@ -259,11 +283,38 @@ def test_server_generate_spans_on_profiler_clock(llama, tmp_path,
 
 def test_warmup_helper_returns_warm_server(llama):
     _, model, params = llama
-    srv = warmup(model, params, ServeConfig(max_new_tokens=2, max_seq=64),
-                 buckets=[(2, 8)])
+    alias = obs.counter("serve.cache_alias")
+    before = alias.value(bucket="2x8")
+    with obs.observe():
+        srv = warmup(model, params,
+                     ServeConfig(max_new_tokens=2, max_seq=64),
+                     buckets=[(2, 8)])
     assert "2x8" in srv.warmup_report
+    # the compiled step aliases the whole cache: it updates it in place
+    assert srv.warmup_report["2x8"]["cache_alias"] == 1.0
+    assert alias.value(bucket="2x8") - before == 1.0
     res = srv.generate([[4, 5]])
     assert res.bucket == "2x8" and len(res.new_tokens[0]) == 2
+
+
+@pytest.mark.parametrize("phase", ["prefill", "step"])
+@pytest.mark.parametrize("arch", ["llama3_2_1b", "xlstm_350m"])
+def test_default_programs_donate_the_cache(arch, phase):
+    """``_default_prefill`` (one pass, or the recurrent families' step
+    loop) and ``_default_step`` take the cache donated: the call deletes
+    the buffers passed in, and the caller goes on with those returned."""
+    cfg = get_smoke_config(arch)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    cache = model.init_cache(2, 16)
+    tokens = jnp.ones((2, 4), jnp.int32)
+    if phase == "prefill":
+        _, new = _default_prefill(model)(params, cache, tokens)
+    else:
+        _, new = _default_step(model)(params, cache, tokens[:, :1],
+                                      jnp.int32(0))
+    assert all(a.is_deleted() for a in jax.tree.leaves(cache))
+    assert not any(a.is_deleted() for a in jax.tree.leaves(new))
 
 
 # --- sweep JSON schema + report -------------------------------------------
