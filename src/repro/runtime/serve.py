@@ -1,41 +1,30 @@
-"""Serving runtime: batched greedy/temperature decoding over the KV cache.
+"""The compiled programs of one (batch, seq) serving bucket.
 
-``generate`` drives model.decode_step with a single jit'd step (position is
-a traced scalar, so one compile serves the whole generation).  Prompts are
-consumed through the same step (teacher forcing) -- robust across every
-model family here, including the recurrent ones whose prefill is the
-recurrence itself.
+``compile_bucket`` AOT-compiles a bucket's cache initialiser, its prefill
+(one pass for models with ``prefill``; the decode step once per prompt
+token, ``_step_loop``, for the recurrent families) and its decode step.
+The prefill and the step take the KV/state cache donated and update it
+in place.  A multi-device ``mesh`` routes every matmul of the traced
+forward through the plan engine (``planned_scope``:
+``repro.plan.planned_matmuls``), so the programs execute solver-derived
+``SchedulePlan``s instead of the local GSPMD baseline.  With per-row position ``offsets`` (models with
+``supports_position_offsets``) a left-padded row decodes at its own
+logical positions, its padding masked out of attention.
 
-Two serving-specific extensions over the seed version:
-
-  * ``mesh=`` routes every matmul in the forward through the plan engine
-    (``repro.plan.planned_matmuls``): decode executes solver-derived
-    ``SchedulePlan``s -- cost-model-ranked (or pinned via ``strategy=``),
-    memoized in the plan cache -- instead of the local GSPMD baseline.
-  * ``lens=`` marks per-request true prompt lengths in a left-padded
-    batch.  Models that support per-row position offsets
-    (``supports_position_offsets``) then mask the padding slots out of
-    attention and place real tokens at their logical positions, so a
-    request decoded inside a bucket emits the same greedy tokens as it
-    would alone (pinned by tests/test_serve.py).
-
-``repro.serve.Server`` builds the production path on top of this module:
-persistent compiled step functions, (batch, seq) bucket routing, AOT
-plan-cache warmup, and latency accounting.
+``batch_requests`` left-pads a request list into a bucket's batch and
+``_sample`` draws the next token.  ``repro.serve.Server`` is the decode
+loop over these programs: bucket routing, warmup and latency accounting.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 from typing import Callable, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
-
-from repro import obs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -65,73 +54,8 @@ class ServeConfig:
                 f"the request")
 
 
-def generate(
-    model, params, prompts: np.ndarray, cfg: ServeConfig,
-    key: Optional[jax.Array] = None,
-    *,
-    mesh=None,
-    strategy: Optional[str] = None,
-    tuning=None,
-    lens: Optional[np.ndarray] = None,
-    prefill_fn=None,
-    step_fn=None,
-) -> np.ndarray:
-    """prompts: (B, S_prompt) int32 -> (B, S_prompt + max_new_tokens).
-
-    ``mesh`` routes the forward through ``planned_matmuls`` (see module
-    docstring); ``strategy`` pins the schedule inside that scope;
-    ``tuning`` (a ``repro.tune`` table or ``Tuner``) prices in-scope plans
-    with measured kernel seconds.  ``lens``
-    gives per-request true lengths of a left-padded batch; models with
-    ``supports_position_offsets`` then decode each row at its own logical
-    positions.  ``prefill_fn``/``step_fn`` inject persistent compiled
-    functions (``repro.serve.Server``); by default fresh jit wrappers are
-    built per call.
-    """
-    b, sp = prompts.shape
-    if b == 0:
-        return np.asarray(prompts)
-    cfg.validate_prompt_len(sp)
-    cache = model.init_cache(b, cfg.max_seq)
-    key = key if key is not None else jax.random.PRNGKey(0)
-
-    offsets = None
-    if lens is not None and getattr(model, "supports_position_offsets", False):
-        offsets = jnp.asarray(sp - np.asarray(lens), jnp.int32)
-
-    tokens = jnp.asarray(prompts, jnp.int32)
-    out = [tokens]
-    scope = planned_scope(mesh, strategy, tuning)
-    with scope:
-        if prefill_fn is None:
-            prefill_fn = _default_prefill(model, mesh, strategy, tuning)
-        if step_fn is None:
-            step_fn = _default_step(model, mesh, strategy, tuning)
-        with obs.span("serve.prefill", batch=b, seq=sp):
-            if offsets is not None:
-                logits, cache = prefill_fn(params, cache, tokens, offsets)
-            else:
-                logits, cache = prefill_fn(params, cache, tokens)
-        if cfg.max_new_tokens == 0:
-            return np.asarray(tokens)
-        cur = _sample(logits, cfg, key)
-        out.append(cur[:, None])
-        for t in range(sp, sp + cfg.max_new_tokens - 1):
-            key, sub = jax.random.split(key)
-            with obs.span("serve.decode_step", batch=b, pos=t):
-                if offsets is not None:
-                    logits, cache = step_fn(params, cache, cur[:, None],
-                                            jnp.int32(t), offsets)
-                else:
-                    logits, cache = step_fn(params, cache, cur[:, None],
-                                            jnp.int32(t))
-            cur = _sample(logits, cfg, sub)
-            out.append(cur[:, None])
-    return np.asarray(jnp.concatenate(out, axis=1))
-
-
 def planned_scope(mesh, strategy: Optional[str] = None, tuning=None):
-    """The plan-routing scope ``generate`` decodes under: route through
+    """The plan-routing scope a bucket's programs trace under: route through
     ``planned_matmuls(mesh, strategy, tuning)`` when a multi-device mesh is
     given, otherwise a null context (the local GSPMD baseline path)."""
     if mesh is not None and getattr(mesh, "size", 1) > 1:
@@ -144,8 +68,8 @@ def planned_scope(mesh, strategy: Optional[str] = None, tuning=None):
 def _prefill_fn(model, mesh, strategy, tuning):
     """The prefill as the jitted function ``prefill``.
 
-    The plan scope is (re-)entered INSIDE the traced function, not just
-    around the call: JAX's trace cache is keyed on the traced callable,
+    The plan scope is entered INSIDE the traced function, not around the
+    call: JAX's trace cache is keyed on the traced callable,
     and equal bound methods (``model.prefill``) would share a jaxpr traced
     earlier WITHOUT the scope -- silently skipping plan routing.  A
     closure per (model, mesh, strategy, tuning) gets its own trace-cache
@@ -185,30 +109,6 @@ def _step_loop(step):
         return logits, cache
 
     return loop
-
-
-@functools.lru_cache(maxsize=None)
-def _default_prefill(model, mesh=None, strategy: Optional[str] = None,
-                     tuning=None):
-    """Memoized per (model, mesh, strategy, tuning) prefill: one-pass for
-    models with ``prefill`` (DecoderLM), the step loop otherwise.  Like
-    ``_default_step`` it takes the cache donated."""
-    if hasattr(model, "prefill"):
-        return jax.jit(_prefill_fn(model, mesh, strategy, tuning),
-                       donate_argnums=(1,))
-    return _step_loop(_default_step(model, mesh, strategy, tuning))
-
-
-@functools.lru_cache(maxsize=None)
-def _default_step(model, mesh=None, strategy: Optional[str] = None,
-                  tuning=None):
-    """Memoized per (model, mesh, strategy, tuning) decode step.
-
-    The cache argument is donated: the model updates it in place, and the
-    buffers passed in are deleted by the call.  A caller rebinds its cache
-    to the one returned and never reads the one it passed."""
-    return jax.jit(_step_fn(model, mesh, strategy, tuning),
-                   donate_argnums=(1,))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -279,10 +179,10 @@ def batch_requests(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Left-pad a list of variable-length prompts into one (B, S) batch.
 
-    Returns ``(batch, lens)``: ``lens[i]`` is request i's true length --
-    pass it to ``generate(lens=...)`` so padded rows decode at their own
-    logical positions.  An empty request list yields an explicit empty
-    (0, 0) batch (generate returns it unchanged).  ``pad_to`` pads the
+    Returns ``(batch, lens)``: ``lens[i]`` is request i's true length, and
+    ``batch.shape[1] - lens`` the row's position offset, so padded rows
+    decode at their own logical positions.  An empty request list yields
+    an explicit empty (0, 0) batch.  ``pad_to`` pads the
     sequence axis to a fixed width (the bucket router's seq bucket).
     """
     if not prompt_list:

@@ -1,11 +1,10 @@
-"""Plan-routed serving server: persistent compiled steps, bucket routing,
-AOT plan-cache warmup, latency accounting.
+"""Plan-routed serving server: the decode loop, bucket routing, AOT
+plan-cache warmup, latency accounting.
 
-The seed-era ``runtime.serve.generate`` builds a fresh ``jax.jit`` wrapper
-per call, so every request pays trace + compile + planning.  ``Server``
-holds one compiled prefill and one compiled decode step per (batch, seq)
-shape for the lifetime of the process, and warms those of a declared
-bucket grid:
+``Server`` is the one serving path.  It holds one compiled prefill and one
+compiled decode step per (batch, seq) shape for the lifetime of the
+process, so a request pays no trace, compile or planning once its shape is
+warm, and warms those of a declared bucket grid:
 
   * ``warmup()`` AOT-compiles each bucket's prefill + decode step
     (``runtime.serve.compile_bucket``: the cache donated and updated in
@@ -51,8 +50,7 @@ import numpy as np
 from repro import obs
 from repro.plan.cache import plan_cache
 from repro.runtime.serve import (BucketPrograms, ServeConfig, _sample,
-                                 batch_requests, compile_bucket,
-                                 planned_scope)
+                                 batch_requests, compile_bucket)
 
 from .buckets import Bucket, as_bucket, route
 
@@ -96,9 +94,10 @@ class ServeResult:
 class Server:
     """Production serving harness over one model + mesh (see module doc).
 
-    ``mesh=None`` serves the local (unrouted) baseline path -- same
-    bucketing and warmup, no plan engine -- which the sweep harness uses
-    as the bitwise-comparison baseline for plan-routed decode.
+    ``mesh=None`` (or a one-device mesh) serves the local GSPMD path --
+    same bucketing, warmup and decode loop, no plan engine: the one-chip
+    serving path, and the baseline a plan-routed server's tokens are
+    compared with.
     """
 
     def __init__(self, model, params, cfg: ServeConfig, *, mesh=None,
@@ -250,35 +249,34 @@ class Server:
 
             out = [tokens]
             step_lat: List[float] = []
-            with planned_scope(self.mesh, self.strategy, self.tuning):
-                with obs.span("serve.prefill", batch=b_rows, seq=sp,
-                              tokens=sum(len(p) for p in prompt_list)):
-                    logits, cache = self._call(progs.prefill, cache,
-                                               tokens, offsets)
-                prefill_logits = logits
-                if self.cfg.max_new_tokens > 0:
-                    with obs.span("serve.first_token"):
-                        cur = _sample(logits, self.cfg, key)
-                        jax.block_until_ready(cur)
-                    ttft = time.perf_counter() - t_start
-                    out.append(cur[:, None])
-                    with obs.span("serve.decode"):
-                        for t in range(sp, sp + self.cfg.max_new_tokens - 1):
-                            with obs.span("serve.key_split"):
-                                key, sub = jax.random.split(key)
-                            t0 = time.perf_counter()
-                            with obs.span("serve.decode_step", batch=b_rows,
-                                          pos=t):
-                                logits, cache = self._call(
-                                    progs.step, cache, cur[:, None],
-                                    jnp.int32(t), offsets)
-                                cur = _sample(logits, self.cfg, sub)
-                                jax.block_until_ready(cur)
-                            step_lat.append(time.perf_counter() - t0)
-                            out.append(cur[:, None])
-                else:
-                    jax.block_until_ready(logits)
-                    ttft = time.perf_counter() - t_start
+            with obs.span("serve.prefill", batch=b_rows, seq=sp,
+                          tokens=sum(len(p) for p in prompt_list)):
+                logits, cache = self._call(progs.prefill, cache,
+                                           tokens, offsets)
+            prefill_logits = logits
+            if self.cfg.max_new_tokens > 0:
+                with obs.span("serve.first_token"):
+                    cur = _sample(logits, self.cfg, key)
+                    jax.block_until_ready(cur)
+                ttft = time.perf_counter() - t_start
+                out.append(cur[:, None])
+                with obs.span("serve.decode"):
+                    for t in range(sp, sp + self.cfg.max_new_tokens - 1):
+                        with obs.span("serve.key_split"):
+                            key, sub = jax.random.split(key)
+                        t0 = time.perf_counter()
+                        with obs.span("serve.decode_step", batch=b_rows,
+                                      pos=t):
+                            logits, cache = self._call(
+                                progs.step, cache, cur[:, None],
+                                jnp.int32(t), offsets)
+                            cur = _sample(logits, self.cfg, sub)
+                            jax.block_until_ready(cur)
+                        step_lat.append(time.perf_counter() - t0)
+                        out.append(cur[:, None])
+            else:
+                jax.block_until_ready(logits)
+                ttft = time.perf_counter() - t_start
             with obs.span("serve.collect"):
                 full = np.asarray(jnp.concatenate(out, axis=1))
                 wall = time.perf_counter() - t_start
@@ -368,15 +366,3 @@ class Server:
         if args[-1] is None:
             args = args[:-1]
         return fn(self.params, cache, *args)
-
-def warmup(model, params, cfg: ServeConfig, *, mesh=None,
-           buckets: Sequence = DEFAULT_BUCKETS,
-           strategy: Optional[str] = None, tuning=None) -> Server:
-    """Build a ``Server`` and AOT-warm its bucket grid in one call:
-    ``server = warmup(model, params, cfg, mesh=mesh, buckets=[(8, 32)])``.
-    Returns the warmed server (its ``warmup_report`` attribute holds the
-    per-bucket accounting)."""
-    server = Server(model, params, cfg, mesh=mesh, strategy=strategy,
-                    tuning=tuning, buckets=buckets)
-    server.warmup_report = server.warmup()
-    return server

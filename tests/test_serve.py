@@ -17,15 +17,13 @@ from collections import Counter
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
 from repro import obs
 from repro.configs import get_smoke_config
 from repro.models.registry import build_model
-from repro.runtime.serve import (ServeConfig, _default_prefill, _default_step,
-                                 batch_requests, generate)
-from repro.serve import Bucket, Server, bucket_grid, route, warmup
+from repro.runtime.serve import ServeConfig, batch_requests, compile_bucket
+from repro.serve import Bucket, Server, bucket_grid, route
 
 
 def _root():
@@ -40,6 +38,13 @@ def llama():
     return cfg, model, params
 
 
+def _served_alone(model, params, scfg, prompts):
+    """Each prompt served alone: a bucketless ``Server`` serves it at its
+    own cold shape, nothing padded and its position offset zero."""
+    srv = Server(model, params, scfg, buckets=())
+    return [srv.generate([p]).sequences[0] for p in prompts]
+
+
 # --- ServeConfig validation (edge cases that used to slip through) ---------
 
 
@@ -52,25 +57,26 @@ def test_serveconfig_rejects_bad_fields():
         ServeConfig(temperature=-0.5)
 
 
-def test_generate_max_new_zero_appends_nothing(llama):
+def test_server_max_new_zero_returns_prompts(llama):
     """max_new_tokens=0 must return the prompts unchanged -- the seed
     version still appended one sampled token."""
     _, model, params = llama
-    prompts = np.array([[5, 6, 7, 8]], np.int32)
-    out = generate(model, params, prompts,
-                   ServeConfig(max_new_tokens=0, max_seq=32))
-    assert out.shape == (1, 4)
-    assert np.array_equal(out, prompts)
+    prompts = [[5, 6, 7, 8]]
+    srv = Server(model, params, ServeConfig(max_new_tokens=0, max_seq=32),
+                 buckets=())
+    res = srv.generate(prompts)
+    assert res.sequences == prompts
+    assert res.new_tokens == [[]]
 
 
-def test_generate_cache_overrun_raises(llama):
+def test_server_cache_overrun_raises(llama):
     """prompt + max_new_tokens > max_seq used to silently overrun the KV
     cache; now it's a ValueError before any compute."""
     _, model, params = llama
-    prompts = np.array([[1] * 30], np.int32)
+    srv = Server(model, params, ServeConfig(max_new_tokens=8, max_seq=32),
+                 buckets=())
     with pytest.raises(ValueError, match="max_seq"):
-        generate(model, params, prompts,
-                 ServeConfig(max_new_tokens=8, max_seq=32))
+        srv.generate([[1] * 30])
 
 
 # --- batch_requests --------------------------------------------------------
@@ -82,11 +88,12 @@ def test_batch_requests_empty_list():
     assert batch.shape == (0, 0) and lens.shape == (0,)
 
 
-def test_generate_empty_batch(llama):
+def test_server_empty_request_list_compiles_nothing(llama):
     _, model, params = llama
-    batch, _ = batch_requests([])
-    out = generate(model, params, batch, ServeConfig(max_new_tokens=4))
-    assert out.shape == (0, 0)
+    srv = Server(model, params, ServeConfig(max_new_tokens=4), buckets=())
+    res = srv.generate([])
+    assert res.sequences == [] and res.new_tokens == []
+    assert srv._programs == {}
 
 
 def test_batch_requests_shapes_and_lens():
@@ -140,29 +147,28 @@ def test_greedy_determinism_across_runs_and_batch_order(llama):
     _, model, params = llama
     cfg = ServeConfig(max_new_tokens=5, max_seq=32)
     prompts = [[5, 6, 7], [9, 2, 3, 4]]
-    batch, lens = batch_requests(prompts)
-    a = generate(model, params, batch, cfg, lens=lens)
-    b = generate(model, params, batch, cfg, lens=lens)
-    assert np.array_equal(a, b)
+    srv = Server(model, params, cfg, buckets=())
+    a = srv.generate(prompts)
+    b = srv.generate(prompts)
+    assert a.sequences == b.sequences
     # reversed batch order: same per-request tokens, permuted rows
-    rbatch, rlens = batch_requests(prompts[::-1])
-    r = generate(model, params, rbatch, cfg, lens=rlens)
-    for i, p in enumerate(prompts):
-        fwd = a[i, batch.shape[1] - lens[i]:]
-        rev = r[1 - i, rbatch.shape[1] - rlens[1 - i]:]
-        assert np.array_equal(fwd, rev), f"request {i} depends on batch order"
+    r = srv.generate(prompts[::-1])
+    for i in range(len(prompts)):
+        assert a.sequences[i] == r.sequences[1 - i], (
+            f"request {i} depends on batch order")
 
 
 def test_temperature_sampling_reproducible_under_fixed_key(llama):
     _, model, params = llama
     cfg = ServeConfig(max_new_tokens=6, max_seq=32, temperature=0.8)
-    prompts = np.array([[5, 6, 7], [9, 2, 3]], np.int32)
+    prompts = [[5, 6, 7], [9, 2, 3]]
+    srv = Server(model, params, cfg, buckets=())
     key = jax.random.PRNGKey(42)
-    a = generate(model, params, prompts, cfg, key=key)
-    b = generate(model, params, prompts, cfg, key=key)
-    assert np.array_equal(a, b)
-    c = generate(model, params, prompts, cfg, key=jax.random.PRNGKey(7))
-    assert a.shape == c.shape
+    a = srv.generate(prompts, key=key)
+    b = srv.generate(prompts, key=key)
+    assert a.sequences == b.sequences
+    c = srv.generate(prompts, key=jax.random.PRNGKey(7))
+    assert [len(s) for s in a.sequences] == [len(s) for s in c.sequences]
 
 
 # --- left-padding invariance ----------------------------------------------
@@ -178,14 +184,43 @@ def test_left_pad_invariance(arch):
     params = model.init(jax.random.PRNGKey(0))
     scfg = ServeConfig(max_new_tokens=4, max_seq=32)
     prompts = [[5, 6, 7], [9, 2, 3, 4, 1, 8]]
-    batch, lens = batch_requests(prompts)
-    padded = generate(model, params, batch, scfg, lens=lens)
+    srv = Server(model, params, scfg, buckets=[(2, 8)])
+    srv.warmup()
+    padded = srv.generate(prompts)
+    assert padded.bucket == "2x8"
+    alone = _served_alone(model, params, scfg, prompts)
+    for i in range(len(prompts)):
+        assert alone[i] == padded.sequences[i], (
+            f"{arch} request {i}: alone {alone[i]} != "
+            f"padded {padded.sequences[i]}")
+
+
+@pytest.mark.parametrize("arch", ["llama3_2_1b", "minicpm3_4b"])
+def test_served_greedy_matches_forward(arch):
+    """The served greedy tokens are what the model computes: each prompt's
+    argmax continuation by ``model.forward`` over its unpadded, growing
+    sequence, an oracle that shares no program with the serving path.
+    fp32, so the prefill/step and full-forward accumulation orders cannot
+    flip an argmax tie."""
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    new = 4
+    srv = Server(model, params, ServeConfig(max_new_tokens=new, max_seq=32),
+                 buckets=[(4, 8)])
+    srv.warmup()
+    prompts = [[5, 6, 7], [9, 2, 3, 4, 1, 8]]
+    res = srv.generate(prompts)
+    assert res.bucket == "4x8"
+    forward = jax.jit(model.forward)
     for i, p in enumerate(prompts):
-        alone = generate(model, params, np.asarray([p], np.int32), scfg)
-        row = padded[i, batch.shape[1] - lens[i]:]
-        assert np.array_equal(alone[0], row), (
-            f"{arch} request {i}: alone {alone[0].tolist()} != "
-            f"padded {row.tolist()}")
+        seq = list(p)
+        for _ in range(new):
+            logits, _ = forward(params, jnp.asarray([seq], jnp.int32))
+            seq.append(int(jnp.argmax(logits[0, -1])))
+        assert res.sequences[i] == seq, (
+            f"{arch} request {i}: served {res.sequences[i]} != "
+            f"forward {seq}")
 
 
 def test_server_bucket_invariance_and_trimming(llama):
@@ -200,10 +235,10 @@ def test_server_bucket_invariance_and_trimming(llama):
     res = srv.generate(prompts)
     assert res.bucket == "4x8"
     assert len(res.sequences) == 2           # dummy rows trimmed
+    alone = _served_alone(model, params, scfg, prompts)
     for i, p in enumerate(prompts):
-        alone = generate(model, params, np.asarray([p], np.int32), scfg)
-        assert res.sequences[i] == alone[0].tolist()
-        assert res.new_tokens[i] == alone[0, len(p):].tolist()
+        assert res.sequences[i] == alone[i]
+        assert res.new_tokens[i] == alone[i][len(p):]
 
 
 # --- Server edge behavior --------------------------------------------------
@@ -227,7 +262,8 @@ def test_server_empty_cold_and_null_latency(llama):
 def test_server_compiles_a_cold_shape_once(llama):
     """A batch that fits no bucket is served at its own shape, through the
     same compiled programs as a bucket's, compiled on first use and kept:
-    a second such batch compiles nothing and decodes as the plain path."""
+    a second such batch compiles nothing and decodes each prompt as it is
+    decoded alone."""
     _, model, params = llama
     scfg = ServeConfig(max_new_tokens=3, max_seq=64)
     srv = Server(model, params, scfg, buckets=[(2, 8)])
@@ -239,10 +275,7 @@ def test_server_compiles_a_cold_shape_once(llama):
     assert first.bucket is None and second.bucket is None
     assert srv._programs == shapes and len(shapes) == 2
     assert second.new_tokens == first.new_tokens
-    batch, lens = batch_requests(prompts)
-    plain = generate(model, params, batch, scfg, lens=lens)
-    for i, p in enumerate(prompts):
-        assert first.new_tokens[i] == plain[i, batch.shape[1]:].tolist()
+    assert first.sequences == _served_alone(model, params, scfg, prompts)
 
 
 def test_server_generate_spans_on_profiler_clock(llama, tmp_path,
@@ -281,17 +314,17 @@ def test_server_generate_spans_on_profiler_clock(llama, tmp_path,
         "batch": 4, "seq": 8, "tokens": sum(len(p) for p in prompts)}
 
 
-def test_warmup_helper_returns_warm_server(llama):
+def test_server_warmup_report(llama):
     _, model, params = llama
     alias = obs.counter("serve.cache_alias")
     before = alias.value(bucket="2x8")
+    srv = Server(model, params, ServeConfig(max_new_tokens=2, max_seq=64),
+                 buckets=[(2, 8)])
     with obs.observe():
-        srv = warmup(model, params,
-                     ServeConfig(max_new_tokens=2, max_seq=64),
-                     buckets=[(2, 8)])
-    assert "2x8" in srv.warmup_report
+        report = srv.warmup()
+    assert "2x8" in report
     # the compiled step aliases the whole cache: it updates it in place
-    assert srv.warmup_report["2x8"]["cache_alias"] == 1.0
+    assert report["2x8"]["cache_alias"] == 1.0
     assert alias.value(bucket="2x8") - before == 1.0
     res = srv.generate([[4, 5]])
     assert res.bucket == "2x8" and len(res.new_tokens[0]) == 2
@@ -299,20 +332,23 @@ def test_warmup_helper_returns_warm_server(llama):
 
 @pytest.mark.parametrize("phase", ["prefill", "step"])
 @pytest.mark.parametrize("arch", ["llama3_2_1b", "xlstm_350m"])
-def test_default_programs_donate_the_cache(arch, phase):
-    """``_default_prefill`` (one pass, or the recurrent families' step
-    loop) and ``_default_step`` take the cache donated: the call deletes
-    the buffers passed in, and the caller goes on with those returned."""
+def test_bucket_programs_donate_the_cache(arch, phase):
+    """A bucket's prefill (one pass, or the recurrent families' step loop)
+    and step take the cache donated: the call deletes the buffers passed
+    in, and the caller goes on with those returned."""
     cfg = get_smoke_config(arch)
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    cache = model.init_cache(2, 16)
+    offsets = bool(getattr(model, "supports_position_offsets", False))
+    progs = compile_bucket(model, params, 2, 4, 16, offsets=offsets)
+    rows = (jnp.zeros((2,), jnp.int32),) if offsets else ()
+    cache = progs.init_cache()
     tokens = jnp.ones((2, 4), jnp.int32)
     if phase == "prefill":
-        _, new = _default_prefill(model)(params, cache, tokens)
+        _, new = progs.prefill(params, cache, tokens, *rows)
     else:
-        _, new = _default_step(model)(params, cache, tokens[:, :1],
-                                      jnp.int32(0))
+        _, new = progs.step(params, cache, tokens[:, :1], jnp.int32(0),
+                            *rows)
     assert all(a.is_deleted() for a in jax.tree.leaves(cache))
     assert not any(a.is_deleted() for a in jax.tree.leaves(new))
 
@@ -388,13 +424,13 @@ def test_sweep_baseline_diff_margin():
 _ROUTED_SCRIPT = r"""
 import dataclasses, os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import jax, numpy as np
+import jax
 from repro import obs
 from repro.configs import get_smoke_config
 from repro.models.registry import build_model
 from repro.plan import cache_info
-from repro.runtime.serve import ServeConfig, batch_requests, generate
-from repro.serve import Server, warmup
+from repro.runtime.serve import ServeConfig
+from repro.serve import Server
 from repro.mesh import make_mesh
 
 devs = jax.devices()
@@ -411,8 +447,9 @@ base.warmup()
 rb = base.generate(prompts)
 
 with obs.observe() as rec:
-    srv = warmup(model, params, scfg, mesh=mesh, buckets=[(2, 8)])
-    assert srv.warmup_report["2x8"]["plans"] > 0, srv.warmup_report
+    srv = Server(model, params, scfg, mesh=mesh, buckets=[(2, 8)])
+    report = srv.warmup()
+    assert report["2x8"]["plans"] > 0, report
     rp = srv.generate(prompts)
     rep = srv.cache_report()
 
@@ -425,12 +462,6 @@ assert rp.plan_probe["probed"] > 0 and rp.plan_probe["missing"] == 0, \
     rp.plan_probe
 # plan-routed greedy tokens == unrouted baseline, bitwise
 assert rb.sequences == rp.sequences, (rb.sequences, rp.sequences)
-
-# the module-level generate(mesh=...) path agrees too
-batch, lens = batch_requests(prompts, pad_to=8)
-routed = generate(model, params, batch, scfg, mesh=mesh, lens=lens)
-unrouted = generate(model, params, batch, scfg, lens=lens)
-assert np.array_equal(routed, unrouted), (routed, unrouted)
 
 # second batch stays pinned at 100% hits
 srv.generate([[4, 4], [7, 7, 7]])

@@ -145,14 +145,16 @@ class TestServe:
     def test_generate_greedy_deterministic(self):
         from repro.configs import get_smoke_config
         from repro.models.registry import build_model
-        from repro.runtime.serve import ServeConfig, generate
+        from repro.runtime.serve import ServeConfig
+        from repro.serve import Server
 
         cfg = get_smoke_config("llama3.2-1b")
         model = build_model(cfg)
         params = model.init(jax.random.PRNGKey(0))
-        prompts = np.array([[1, 2, 3], [4, 5, 6]], np.int32)
+        prompts = [[1, 2, 3], [4, 5, 6]]
         sc = ServeConfig(max_new_tokens=6, max_seq=32)
-        a = generate(model, params, prompts, sc)
-        b = generate(model, params, prompts, sc)
-        np.testing.assert_array_equal(a, b)
-        assert a.shape == (2, 9)
+        srv = Server(model, params, sc, buckets=())
+        a = srv.generate(prompts)
+        b = srv.generate(prompts)
+        assert a.sequences == b.sequences
+        assert [len(s) for s in a.sequences] == [9, 9]

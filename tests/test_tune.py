@@ -303,7 +303,7 @@ from repro.configs import get_smoke_config
 from repro.mesh import make_mesh
 from repro.models.registry import build_model
 from repro.runtime.serve import ServeConfig
-from repro.serve import warmup
+from repro.serve import Server
 from repro.tune import Tuner
 
 devs = jax.devices()
@@ -314,7 +314,8 @@ params = model.init(jax.random.PRNGKey(0))
 scfg = ServeConfig(max_new_tokens=4, max_seq=64)
 
 tuner = Tuner(reps=1, max_candidates=2, interpret=True)
-srv = warmup(model, params, scfg, mesh=mesh, buckets=[(2, 8)], tuning=tuner)
+srv = Server(model, params, scfg, mesh=mesh, buckets=[(2, 8)], tuning=tuner)
+srv.warmup()
 assert tuner.stats["searches"] > 0, tuner.stats  # warmup tuned the buckets
 
 r = srv.generate([[5, 6, 7], [9, 2, 3, 4, 1]])
